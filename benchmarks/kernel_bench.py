@@ -32,7 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import fused_combine, fused_mac_ref, mf_combine
+from repro.kernels import (fused_combine, fused_mac_ref, interpret_mode,
+                           mf_combine)
 
 SCHEMA_VERSION = "repro.bench.kernel/v1"
 
@@ -79,14 +80,13 @@ def _record(name: str, backend: str, U: int, K: int, N: int, dt: float,
         "channel_bytes": channel_bytes,
         # execution context, so the accumulated trajectory is comparable
         # across runners (CPU-interpret vs TPU-compiled, 1 vs N devices).
-        # Only the Pallas cores fall back to interpret off-TPU; the jnp
-        # oracle is XLA-compiled everywhere.
+        # Only the Pallas cores are interpreted (on CPU); the jnp oracle
+        # is XLA-compiled everywhere.
         "device_count": jax.device_count(),
         "jax_backend": jax.default_backend(),
-        "exec_mode": ("compiled"
-                      if backend == "oracle"
-                      or jax.default_backend() == "tpu"
-                      else "interpret"),
+        "exec_mode": ("interpret"
+                      if backend != "oracle" and interpret_mode()
+                      else "compiled"),
     }
 
 

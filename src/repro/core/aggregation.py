@@ -14,26 +14,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # moved between modules across jax versions
-    from jax.custom_batching import custom_vmap as _custom_vmap
-except ImportError:  # pragma: no cover - version fallback
-    from jax._src.custom_batching import custom_vmap as _custom_vmap
-
-
-@_custom_vmap
 def fence(tree):
-    """`jax.lax.optimization_barrier` with a vmap rule.
-
-    The pinned jax 0.4.37 has no batching rule for the barrier
-    primitive, so a bare barrier breaks the sweep engine's ``vmap``
-    seed-batch mode; under vmap this fences the whole batched value
-    instead (same isolation, one barrier)."""
+    """`jax.lax.optimization_barrier`: keeps XLA from fusing the fenced
+    values with their neighbours.  Under the sweep engine's ``vmap``
+    seed-batch mode it fences the whole batched value."""
     return jax.lax.optimization_barrier(tree)
-
-
-@fence.def_vmap
-def _fence_vmap(axis_size, in_batched, tree):
-    return fence(tree), in_batched[0]
 
 
 @dataclass(frozen=True)

@@ -113,22 +113,14 @@ class OTADistConfig:
 # axis helpers (valid inside shard_map over ('pod','cluster','user'))
 # ---------------------------------------------------------------------------
 
-def _axis_size(name: str):
-    """`jax.lax.axis_size` only exists on newer jax; `psum(1, name)` is
-    the portable spelling (constant-folded, no communication)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
 def cluster_id():
     """Global cluster index = pod * clusters_per_pod + cluster."""
-    return (jax.lax.axis_index("pod") * _axis_size("cluster")
+    return (jax.lax.axis_index("pod") * jax.lax.axis_size("cluster")
             + jax.lax.axis_index("cluster"))
 
 
 def user_id():
-    return cluster_id() * _axis_size("user") + jax.lax.axis_index("user")
+    return cluster_id() * jax.lax.axis_size("user") + jax.lax.axis_index("user")
 
 
 def _noise_like(key, tree, std_tree_or_scalar):

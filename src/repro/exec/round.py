@@ -50,9 +50,8 @@ metrics on some odd fused-backend shapes, where XLA:CPU layout
 assignment rounds the energy fold 1 ULP apart between the two
 programs (bounded by the same tests).
 
-Everything runs *fully manual* (both mesh axes) — the pinned jax
-0.4.37 cannot lower partial-auto shard_map on XLA:CPU (see
-`repro.sharding.api.shard_map`).
+Everything runs *fully manual* (both mesh axes): every collective is
+explicit, so the program is the same on every backend.
 """
 from __future__ import annotations
 
@@ -73,7 +72,7 @@ from repro.core.whfl import (WHFLConfig, make_local_train,
                              validate_participation)
 from repro.exec.mesh import pad_plan_for
 from repro.ft.guard import guard_estimate, validate_guard
-from repro.kernels import fused_mac
+from repro.kernels import fused_mac, interpret_mode
 from repro.obs.telemetry import (cluster_telemetry, edge_telemetry_init,
                                  is_telemetry, is_telemetry_zero)
 # the executor's symbol padding must agree with the kernel's rounding
@@ -129,7 +128,7 @@ def _build_round_parts(loss_fn: Callable, opt: Optimizer, topo: Topology,
     Np = _round_up(N, mu)       # symbol axis padded to split over 'user'
     N_loc = Np // mu
     local_train = make_local_train(loss_fn, opt, cfg)
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
 
     # Participation / robustness gates mirror the single engine's
     # Python-level branches (repro.core.whfl.make_round_fn): a full
@@ -330,17 +329,11 @@ def _build_round_parts(loss_fn: Callable, opt: Optimizer, topo: Topology,
         # the mesh), so the per-element accumulation order — and with it
         # the bitwise mesh-invariance — is preserved: the u-blocking is
         # the canonical one every fused cluster-hop path shares
-        # (block_n only retiles the independent symbol columns, so a
-        # bigger lane block at very large U amortizes interpret-mode
-        # grid overhead without touching a bit).
-        blocks = dict(block_u=bu_c)
-        if C * M >= 8192:
-            blocks["block_n"] = 1024
         y_re, y_im = fused_mac(
             _seed_words(key), t_re, t_im, amp_loc, own_loc, K=topo.K,
             sigma_h2=topo.sigma_h2, sigma_z2=topo.sigma_z2,
-            rx_base=ci * C_loc, n_base=ui * N_loc, interpret=interpret,
-            **blocks)
+            rx_base=ci * C_loc, n_base=ui * N_loc, block_u=bu_c,
+            interpret=interpret)
         scale = P_t * topo.sigma_h2 * bb_loc[:, None]
 
         def collect(y):                       # [C_loc, N_loc] -> [Cp, N]
@@ -383,13 +376,12 @@ def _build_round_parts(loss_fn: Callable, opt: Optimizer, topo: Topology,
         u0 = ci * U_loc            # this tile's global u-block origin
         amp_t = jax.lax.dynamic_slice_in_dim(amp_v, u0, U_loc, 1)
         own_t = jax.lax.dynamic_slice_in_dim(own_v, u0, U_loc, 1)
-        blocks = dict(block_n=1024) if C * M >= 8192 else {}
         words = _seed_words(key)
         pr_re, pr_im, pm_re, pm_im = fused_mac_partials(
             words, t_re, t_im, amp_t, own_t, K=topo.K,
             sigma_h2=topo.sigma_h2, rx_base=0, u_base=u0,
-            n_base=ui * N_loc, block_u=bu_c, interpret=interpret,
-            **blocks)                       # 4 x [Cp, G_loc, Kp, N_loc]
+            n_base=ui * N_loc, block_u=bu_c,
+            interpret=interpret)            # 4 x [Cp, G_loc, Kp, N_loc]
 
         def order(p):
             # gather every shard's blocks and lay them out in global

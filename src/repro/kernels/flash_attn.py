@@ -122,10 +122,8 @@ def flash_mha(q, k, v, *, causal: bool = True, q_block: int = 256,
             pltpu.VMEM((QB, 128), jnp.float32),  # running denominator
         ],
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel",
-                                             "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(q, k, v)
 
 

@@ -7,13 +7,13 @@ that slab cannot exist.  This kernel removes it: the Rayleigh fading
 channels `h[u, k, n]` and the receiver noise `z[k, n]` are *derived on
 the fly inside the kernel* from a counter-based PRNG, so the hop reads
 only the `[U, N]` transmit symbols and O(block) scratch — channel
-memory drops from O(U*K*N) to O(block_u * block_k * block_n).
+memory drops from O(U*K*N) to O(block_k * block_n).
 
 PRNG: threefry2x32 (the same 20-round Feistel jax.random uses),
-implemented with pure `jnp` uint32 ops so the kernel runs *identically*
-under ``interpret=True`` on CPU and compiled on TPU (the pinned jax
-0.4.37 makes `pltpu.prng_*` fragile off-TPU, and its draws would not be
-reproducible by the pure-jnp reference).  Each complex element draws
+implemented with pure `jnp` uint32 ops so the kernel draws the same
+values under ``interpret=True`` on CPU and compiled on TPU, and the
+pure-jnp reference reproduces them (the `pltpu.prng_*` draws would not
+be).  Each complex element draws
 one threefry block keyed on ``(seed, rx, stream)`` with the counter
 ``(u * Kstride + k, n)``; the two 32-bit outputs feed a Box–Muller
 transform producing the (re, im) pair.  Counters depend only on the
@@ -45,9 +45,14 @@ draw — are untouched by padding (see `repro.exec.round`).
 
 Layout mirrors `ota_combine`: planar float32 (re, im), symbol axis N in
 lanes, grid ``(B_rx, N/bn, K/bk, U/bu)`` with the two reduction axes
-(antennas, transmitters) minor.  Received signal and matched filter are
-accumulated in VMEM scratch over the U axis; the output block is
-revisited across K and finalized at the last U step.  The B_rx axis
+(antennas, transmitters) minor.  The blocks meet Mosaic's (8, 128)
+tiling for every bu: t is laid out ``[U/bu, bu, N]`` (a block spans
+the whole bu axis), the per-user amp / w scalars and the counter words
+live in SMEM, and inside a block the users are visited one at a time
+(`_block_sums`), so the live channel tile is [bk, bn], never
+[bu, bk, bn].  Received signal and matched filter are accumulated in
+VMEM scratch over the U axis; the output block is revisited across K
+and finalized at the last U step.  The B_rx axis
 batches receiving stations (cluster hop: one dispatch for all C ISs,
 each with its own `[U]` amplitude row and matched-filter mask) — every
 rx draws independent channels, as in the paper's model.
@@ -84,8 +89,11 @@ def canonical_block_u(M: int, cap: int = 1024) -> int:
     identically.  This canonical size is a pure function of the
     per-cluster user count M: it always divides M (so u-blocks never
     straddle a cluster — and with it a u-shard — boundary) and halves
-    down from M only while above `cap`, keeping interpret-mode grid
-    overhead bounded at large M.
+    down from M only while above `cap`.  The cap bounds the kernel's
+    double-buffered [bu, bn] symbol blocks (8 MiB at bu = 1024,
+    bn = 512: half of a v5e's scoped VMEM) and the interpret-mode grid
+    overhead.  Every bu is legal on a TPU, M = 5 included: the symbol
+    blocks span the whole bu axis and amp / w are SMEM scalars.
     """
     bu = max(int(M), 1)
     while bu > cap and bu % 2 == 0:
@@ -128,8 +136,9 @@ def _threefry2x32(k0, k1, x0, x1):
 def _box_muller(b0, b1):
     """Two uint32 words -> two independent N(0, 1) float32 draws."""
     # u1 in (0, 1] (log-safe), u2 in [0, 1); 24-bit mantissa precision
-    u1 = 1.0 - (b0 >> 8).astype(jnp.float32) * _U24
-    u2 = (b1 >> 8).astype(jnp.float32) * _U24
+    # the 24-bit values fit int32 exactly (Mosaic has no uint32 -> f32)
+    u1 = 1.0 - (b0 >> 8).astype(jnp.int32).astype(jnp.float32) * _U24
+    u2 = (b1 >> 8).astype(jnp.int32).astype(jnp.float32) * _U24
     r = jnp.sqrt(-2.0 * jnp.log(u1))
     theta = _TWO_PI * u2
     return r * jnp.cos(theta), r * jnp.sin(theta)
@@ -155,63 +164,113 @@ def _stream_keys(s0, s1, rx, tag):
 # the fused kernel
 # ---------------------------------------------------------------------------
 
+_GROUP = 8   # users per sublane-aligned group of the in-block user loop
+
+
+def _counter_words(seed, rx_base, u_base, n_base):
+    """uint32 [5]: the two seed words, then the global counter bases
+    (rx, u, n) — the kernel's scalar operands, kept in SMEM."""
+    base = jnp.stack([jnp.asarray(0 if v is None else v, jnp.uint32)
+                      for v in (rx_base, u_base, n_base)])
+    return jnp.concatenate([jnp.asarray(seed).astype(jnp.uint32).reshape(2),
+                            base])
+
+
+def _tile_counters(words_ref, bk: int, bn: int):
+    """(rx, kk, nn): this grid step's rx counter word and the [bk, bn]
+    antenna / symbol counter words of its (k, n) tile."""
+    rx = words_ref[2] + pl.program_id(0).astype(jnp.uint32)
+    k0 = (pl.program_id(2) * bk).astype(jnp.uint32)
+    n0 = (pl.program_id(1) * bn).astype(jnp.uint32)
+    kk = jax.lax.broadcasted_iota(jnp.uint32, (bk, bn), 0) + k0
+    nn = (jax.lax.broadcasted_iota(jnp.uint32, (bk, bn), 1) + n0
+          + words_ref[4])
+    return rx, kk, nn
+
+
+def _block_sums(words_ref, t_re_ref, t_im_ref, amp_ref, w_ref, rx, kk, nn,
+                *, Kstride: int, sigma_h: float, bu: int):
+    """This u-block's accumulators, each [bk, bn]:
+
+        r  = sum_u h[u] t[u]      mf = sum_u w[u] h[u]
+
+    summed from zero over the block's users in ascending order — the
+    per-block term both kernels share.  Users are visited in
+    sublane-aligned groups of `_GROUP` (a `fori_loop`, plus a static
+    tail when bu is not a multiple of the group), one user at a time:
+    each user's channel tile is drawn, folded and dropped, so no
+    [bu, bk, bn] temporary ever exists.  amp/w are per-user scalars
+    read from SMEM; each t row broadcasts over the antenna sublanes.
+    """
+    hk0, hk1 = _stream_keys(words_ref[0], words_ref[1], rx, _TAG_CHAN)
+    u0 = (pl.program_id(3) * bu).astype(jnp.uint32) + words_ref[3]
+
+    def user(j, t_re, t_im, acc):
+        a = amp_ref[0, j]
+        wa = w_ref[0, j] * a                 # matched filter uses w_u * h_u
+        uu = u0 + jax.lax.convert_element_type(j, jnp.uint32)
+        g_re, g_im = _cx_normal(hk0, hk1, uu * np.uint32(Kstride) + kk, nn,
+                                sigma_h)
+        h_re, h_im = a * g_re, a * g_im
+        r_re, r_im, mf_re, mf_im = acc
+        return (r_re + (h_re * t_re - h_im * t_im),
+                r_im + (h_re * t_im + h_im * t_re),
+                mf_re + wa * g_re, mf_im + wa * g_im)
+
+    def group(start, size, acc):
+        t_re = t_re_ref[pl.ds(start, size), :]            # [size, bn]
+        t_im = t_im_ref[pl.ds(start, size), :]
+        for i in range(size):
+            acc = user(start + i, t_re[i:i + 1], t_im[i:i + 1], acc)
+        return acc
+
+    zero = jnp.zeros(kk.shape, jnp.float32)
+    acc = (zero, zero, zero, zero)
+    n_full = bu // _GROUP
+    if n_full:
+        acc = jax.lax.fori_loop(
+            0, n_full,
+            lambda g, acc: group(pl.multiple_of(g * _GROUP, _GROUP),
+                                 _GROUP, acc), acc)
+    if bu % _GROUP:
+        acc = group(n_full * _GROUP, bu % _GROUP, acc)
+    return acc
+
+
 def _fused_kernel(words_ref, t_re_ref, t_im_ref, amp_ref, w_ref, y_ref,
                   r_re, r_im, mf_re, mf_im, *, K: int, Kstride: int,
                   sigma_h: float, sigma_z: float, bu: int, bk: int, bn: int):
-    """One (rx, n, k, u) block.
+    """One (rx, n, k, u) grid step.
 
-    `words_ref` [1, 8] uint32 packs the two seed words plus the global
-    counter bases (rx_base, u_base, n_base) — see module docstring.
-    Scratch r (received signal) and mf (matched filter), both [bk, bn],
-    accumulate over the U grid axis; y [1, 2, bn] accumulates the
-    conj(mf) * r antenna fold over the K grid axis.
+    `words_ref` (SMEM, uint32 [5]) holds the seed words and the global
+    counter bases (see `_counter_words`).  Scratch r (received signal)
+    and mf (matched filter), both [bk, bn], start from the noise z and
+    zero at the first u step and gain one `_block_sums` term per u
+    step; y [2, bn] accumulates the conj(mf) * r antenna fold over the
+    K grid axis.
     """
-    c = pl.program_id(0)
-    ni, ki, ui = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-    n_u = pl.num_programs(3)
-    s0, s1 = words_ref[0, 0], words_ref[0, 1]
-    rx_base, u_base, n_base = (words_ref[0, 2], words_ref[0, 3],
-                               words_ref[0, 4])
-    rx = rx_base + c.astype(jnp.uint32)
-
-    k0 = ki * bk
-    n0 = ni * bn
-    kk = jax.lax.broadcasted_iota(jnp.uint32, (bk, bn), 0) + k0.astype(
-        jnp.uint32)
-    nn = (jax.lax.broadcasted_iota(jnp.uint32, (bk, bn), 1)
-          + n0.astype(jnp.uint32) + n_base)
+    ki, ui = pl.program_id(2), pl.program_id(3)
+    rx, kk, nn = _tile_counters(words_ref, bk, bn)
 
     @pl.when(ui == 0)
     def _init_block():
         # receiver noise z ~ CN(0, sigma_z2) seeds the r accumulator
-        zk0, zk1 = _stream_keys(s0, s1, rx, _TAG_NOISE)
+        zk0, zk1 = _stream_keys(words_ref[0], words_ref[1], rx, _TAG_NOISE)
         z_re, z_im = _cx_normal(zk0, zk1, kk, nn, sigma_z)
         r_re[...] = z_re
         r_im[...] = z_im
         mf_re[...] = jnp.zeros_like(mf_re)
         mf_im[...] = jnp.zeros_like(mf_im)
 
-    # this u-block's channels: h[u, k, n] = amp_u * g, g ~ CN(0, sigma_h2)
-    hk0, hk1 = _stream_keys(s0, s1, rx, _TAG_CHAN)
-    uu = (jax.lax.broadcasted_iota(jnp.uint32, (bu, bk, bn), 0)
-          + (ui * bu).astype(jnp.uint32) + u_base)
-    w0 = uu * np.uint32(Kstride) + kk[None, :, :]
-    w1 = jnp.broadcast_to(nn[None, :, :], (bu, bk, bn))
-    g_re, g_im = _cx_normal(hk0, hk1, w0, w1, sigma_h)
+    pr_re, pr_im, pm_re, pm_im = _block_sums(
+        words_ref, t_re_ref, t_im_ref, amp_ref, w_ref, rx, kk, nn,
+        Kstride=Kstride, sigma_h=sigma_h, bu=bu)
+    r_re[...] += pr_re
+    r_im[...] += pr_im
+    mf_re[...] += pm_re
+    mf_im[...] += pm_im
 
-    amp = amp_ref[0, :]                       # [bu]
-    wa = (w_ref[0, :] * amp)[:, None, None]   # matched filter uses w_u * h_u
-    h_re = amp[:, None, None] * g_re
-    h_im = amp[:, None, None] * g_im
-    t_re = t_re_ref[...][:, None, :]          # [bu, 1, bn]
-    t_im = t_im_ref[...][:, None, :]
-
-    r_re[...] += jnp.sum(h_re * t_re - h_im * t_im, axis=0)
-    r_im[...] += jnp.sum(h_re * t_im + h_im * t_re, axis=0)
-    mf_re[...] += jnp.sum(wa * g_re, axis=0)
-    mf_im[...] += jnp.sum(wa * g_im, axis=0)
-
-    @pl.when(ui == n_u - 1)
+    @pl.when(ui == pl.num_programs(3) - 1)
     def _finish_block():
         @pl.when(ki == 0)
         def _init_out():
@@ -221,8 +280,39 @@ def _fused_kernel(words_ref, t_re_ref, t_im_ref, amp_ref, w_ref, y_ref,
         mask = (kk < np.uint32(K)).astype(jnp.float32)
         a, b = mf_re[...], mf_im[...]
         p, q = r_re[...], r_im[...]
-        y_ref[0, 0, :] += jnp.sum(mask * (a * p + b * q), axis=0)
-        y_ref[0, 1, :] += jnp.sum(mask * (a * q - b * p), axis=0)
+        y_ref[0:1, :] += jnp.sum(mask * (a * p + b * q), axis=0,
+                                 keepdims=True)
+        y_ref[1:2, :] += jnp.sum(mask * (a * q - b * p), axis=0,
+                                 keepdims=True)
+
+
+def _blocking(N: int, K: int, block_n: int, block_k: int):
+    bn = min(block_n, _round_up(N, 128))
+    bk = min(block_k, K)
+    if bk > 128:
+        raise ValueError(f"block_k must be <= 128, got {bk}")
+    return bn, bk
+
+
+def _kernel_operands(t_re, t_im, amp, w, bu: int, Np: int):
+    """Lay the operands out for the grid: t [U, Np] -> [G, bu, Np], so a
+    (bu, bn) block spans the whole bu axis and meets the (8, 128) tiling
+    for every bu, and amp / w [B, U] -> [B, G, 1, bu] SMEM blocks."""
+    B, U = amp.shape
+    G = U // bu
+    t_re = t_re.reshape(G, bu, Np)
+    t_im = t_im.reshape(G, bu, Np)
+    amp = amp.astype(jnp.float32).reshape(B, G, 1, bu)
+    w = w.astype(jnp.float32).reshape(B, G, 1, bu)
+    return t_re, t_im, amp, w
+
+
+def _in_specs(bu: int, bn: int):
+    words = pl.BlockSpec(memory_space=pltpu.SMEM)
+    t = pl.BlockSpec((None, bu, bn), lambda b, n, k, u: (u, 0, n))
+    a = pl.BlockSpec((None, None, 1, bu), lambda b, n, k, u: (b, u, 0, 0),
+                     memory_space=pltpu.SMEM)
+    return [words, t, t, a, a]
 
 
 @functools.partial(
@@ -255,15 +345,13 @@ def fused_mac(seed, t_re, t_im, amp, w, *, K: int, sigma_h2: float,
     """
     U, N = t_re.shape
     B = amp.shape[0]
-    bn = min(block_n, _round_up(N, 128))
-    bk = min(block_k, _round_up(K, 1))
-    if bk > 128:
-        raise ValueError(f"block_k must be <= 128, got {bk}")
+    bn, bk = _blocking(N, K, block_n, block_k)
     bu = min(block_u, U)
     Np, Kp, Up = _round_up(N, bn), _round_up(K, bk), _round_up(U, bu)
 
-    # zero-pad: padded transmitters have amp = w = 0 and contribute
-    # nothing; padded antennas are masked in-kernel; padded symbols are
+    # zero-pad: padded transmitters have amp = w = 0 and add exact
+    # zeros (they draw at their own counter indices, past the real
+    # users'); padded antennas are masked in-kernel; padded symbols are
     # sliced off below.
     if Np != N:
         t_re = jnp.pad(t_re, ((0, 0), (0, Np - N)))
@@ -274,34 +362,25 @@ def fused_mac(seed, t_re, t_im, amp, w, *, K: int, sigma_h2: float,
         amp = jnp.pad(amp, ((0, 0), (0, Up - U)))
         w = jnp.pad(w, ((0, 0), (0, Up - U)))
 
-    base = jnp.stack([jnp.asarray(0 if v is None else v, jnp.uint32)
-                      for v in (rx_base, u_base, n_base)])
-    words = jnp.concatenate([seed.astype(jnp.uint32).reshape(2), base,
-                             jnp.zeros((3,), jnp.uint32)]).reshape(1, 8)
-    grid = (B, Np // bn, Kp // bk, Up // bu)
+    G = Up // bu
+    grid = (B, Np // bn, Kp // bk, G)
     kernel = functools.partial(
         _fused_kernel, K=K, Kstride=_k_stride(K),
         sigma_h=float(np.sqrt(sigma_h2 / 2.0)),
         sigma_z=float(np.sqrt(sigma_z2 / 2.0)), bu=bu, bk=bk, bn=bn)
 
-    seed_spec = pl.BlockSpec((1, 8), lambda b, n, k, u: (0, 0))
-    t_spec = pl.BlockSpec((bu, bn), lambda b, n, k, u: (u, n))
-    a_spec = pl.BlockSpec((1, bu), lambda b, n, k, u: (b, u))
-    y_spec = pl.BlockSpec((1, 2, bn), lambda b, n, k, u: (b, 0, n))
-
     y = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[seed_spec, t_spec, t_spec, a_spec, a_spec],
-        out_specs=y_spec,
+        in_specs=_in_specs(bu, bn),
+        out_specs=pl.BlockSpec((None, 2, bn), lambda b, n, k, u: (b, 0, n)),
         out_shape=jax.ShapeDtypeStruct((B, 2, Np), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)] * 4,
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=(
-                "parallel", "parallel", "arbitrary", "arbitrary"))
-        ) if not interpret else None,
-    )(words, t_re, t_im, amp.astype(jnp.float32), w.astype(jnp.float32))
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "arbitrary", "arbitrary")),
+    )(_counter_words(seed, rx_base, u_base, n_base),
+      *_kernel_operands(t_re, t_im, amp, w, bu, Np))
     return y[:, 0, :N], y[:, 1, :N]
 
 
@@ -313,49 +392,22 @@ def _fused_partial_kernel(words_ref, t_re_ref, t_im_ref, amp_ref, w_ref,
                           pr_re_ref, pr_im_ref, pm_re_ref, pm_im_ref, *,
                           Kstride: int, sigma_h: float, bu: int, bk: int,
                           bn: int):
-    """One (rx, n, k, u) block of `fused_mac_partials`.
-
-    The per-u-block body is the *literal* accumulation expression of
-    `_fused_kernel` — same counters, same [bu, bk, bn] shapes, same
-    ``jnp.sum(..., axis=0)`` — but instead of folding into scratch it
-    writes each block's sum to its own output slot, so a caller owning
-    only a tile of the user axis can emit its blocks and a pinned-order
-    host of the blocks can replay the full kernel's accumulation
-    bit-exactly (`fused_partials_reduce`).  No noise: z is a separate
-    term keyed on the same counter stream (`fused_noise`).
+    """One (rx, n, k, u) grid step of `fused_mac_partials`: the same
+    `_block_sums` term `_fused_kernel` folds into its scratch, written
+    to this block's own output slot instead, so a caller owning only a
+    tile of the user axis can emit its blocks and a pinned-order fold
+    of the blocks replays the full kernel's accumulation
+    (`fused_partials_reduce`).  No noise: z is a separate term keyed on
+    the same counter stream (`fused_noise`).
     """
-    c = pl.program_id(0)
-    ni, ki, ui = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-    s0, s1 = words_ref[0, 0], words_ref[0, 1]
-    rx_base, u_base, n_base = (words_ref[0, 2], words_ref[0, 3],
-                               words_ref[0, 4])
-    rx = rx_base + c.astype(jnp.uint32)
-
-    k0 = ki * bk
-    n0 = ni * bn
-    kk = jax.lax.broadcasted_iota(jnp.uint32, (bk, bn), 0) + k0.astype(
-        jnp.uint32)
-    nn = (jax.lax.broadcasted_iota(jnp.uint32, (bk, bn), 1)
-          + n0.astype(jnp.uint32) + n_base)
-
-    hk0, hk1 = _stream_keys(s0, s1, rx, _TAG_CHAN)
-    uu = (jax.lax.broadcasted_iota(jnp.uint32, (bu, bk, bn), 0)
-          + (ui * bu).astype(jnp.uint32) + u_base)
-    w0 = uu * np.uint32(Kstride) + kk[None, :, :]
-    w1 = jnp.broadcast_to(nn[None, :, :], (bu, bk, bn))
-    g_re, g_im = _cx_normal(hk0, hk1, w0, w1, sigma_h)
-
-    amp = amp_ref[0, :]                       # [bu]
-    wa = (w_ref[0, :] * amp)[:, None, None]
-    h_re = amp[:, None, None] * g_re
-    h_im = amp[:, None, None] * g_im
-    t_re = t_re_ref[...][:, None, :]          # [bu, 1, bn]
-    t_im = t_im_ref[...][:, None, :]
-
-    pr_re_ref[0, 0] = jnp.sum(h_re * t_re - h_im * t_im, axis=0)
-    pr_im_ref[0, 0] = jnp.sum(h_re * t_im + h_im * t_re, axis=0)
-    pm_re_ref[0, 0] = jnp.sum(wa * g_re, axis=0)
-    pm_im_ref[0, 0] = jnp.sum(wa * g_im, axis=0)
+    rx, kk, nn = _tile_counters(words_ref, bk, bn)
+    pr_re, pr_im, pm_re, pm_im = _block_sums(
+        words_ref, t_re_ref, t_im_ref, amp_ref, w_ref, rx, kk, nn,
+        Kstride=Kstride, sigma_h=sigma_h, bu=bu)
+    pr_re_ref[...] = pr_re
+    pr_im_ref[...] = pr_im
+    pm_re_ref[...] = pm_re
+    pm_im_ref[...] = pm_im
 
 
 @functools.partial(
@@ -383,14 +435,11 @@ def fused_mac_partials(seed, t_re, t_im, amp, w, *, K: int, sigma_h2: float,
     NOT included: draw it once globally with `fused_noise` and hand it
     to the fold.  Summing a tile's blocks into the enclosing call's
     fold in ascending global block order replays `fused_mac`'s scratch
-    accumulation bit-exactly (pinned by tests/test_fused_mac.py).
+    accumulation.
     """
     U, N = t_re.shape
     B = amp.shape[0]
-    bn = min(block_n, _round_up(N, 128))
-    bk = min(block_k, _round_up(K, 1))
-    if bk > 128:
-        raise ValueError(f"block_k must be <= 128, got {bk}")
+    bn, bk = _blocking(N, K, block_n, block_k)
     bu = block_u
     if U % bu:
         raise ValueError(
@@ -403,19 +452,12 @@ def fused_mac_partials(seed, t_re, t_im, amp, w, *, K: int, sigma_h2: float,
         t_re = jnp.pad(t_re, ((0, 0), (0, Np - N)))
         t_im = jnp.pad(t_im, ((0, 0), (0, Np - N)))
 
-    base = jnp.stack([jnp.asarray(0 if v is None else v, jnp.uint32)
-                      for v in (rx_base, u_base, n_base)])
-    words = jnp.concatenate([seed.astype(jnp.uint32).reshape(2), base,
-                             jnp.zeros((3,), jnp.uint32)]).reshape(1, 8)
     grid = (B, Np // bn, Kp // bk, G)
     kernel = functools.partial(
         _fused_partial_kernel, Kstride=_k_stride(K),
         sigma_h=float(np.sqrt(sigma_h2 / 2.0)), bu=bu, bk=bk, bn=bn)
-
-    seed_spec = pl.BlockSpec((1, 8), lambda b, n, k, u: (0, 0))
-    t_spec = pl.BlockSpec((bu, bn), lambda b, n, k, u: (u, n))
-    a_spec = pl.BlockSpec((1, bu), lambda b, n, k, u: (b, u))
-    p_spec = pl.BlockSpec((1, 1, bk, bn), lambda b, n, k, u: (b, u, k, n))
+    p_spec = pl.BlockSpec((None, None, bk, bn),
+                          lambda b, n, k, u: (b, u, k, n))
     p_shape = jax.ShapeDtypeStruct((B, G, Kp, Np), jnp.float32)
 
     # every grid step writes its own disjoint output block — no scratch
@@ -423,15 +465,14 @@ def fused_mac_partials(seed, t_re, t_im, amp, w, *, K: int, sigma_h2: float,
     pr_re, pr_im, pm_re, pm_im = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[seed_spec, t_spec, t_spec, a_spec, a_spec],
+        in_specs=_in_specs(bu, bn),
         out_specs=[p_spec] * 4,
         out_shape=[p_shape] * 4,
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=(
-                "parallel", "parallel", "parallel", "parallel"))
-        ) if not interpret else None,
-    )(words, t_re, t_im, amp.astype(jnp.float32), w.astype(jnp.float32))
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            "parallel", "parallel", "parallel", "parallel")),
+    )(_counter_words(seed, rx_base, u_base, n_base),
+      *_kernel_operands(t_re, t_im, amp, w, bu, Np))
     return (pr_re[..., :N], pr_im[..., :N],
             pm_re[..., :N], pm_im[..., :N])
 
@@ -595,7 +636,9 @@ def fused_mac_ref(seed, t_re, t_im, amp, w, *, K: int, sigma_h2: float,
                           rx_base=rx_base, u_base=u_base, n_base=n_base)
     t = jax.lax.complex(t_re, t_im)
     h = amp.astype(jnp.complex64)[:, :, None, None] * g       # [B,U,K,N]
-    r = jnp.einsum("bukn,un->bkn", h, t) + z
-    mf = jnp.einsum("bu,bukn->bkn", w.astype(jnp.complex64), h)
+    # full f32 products on every backend (a TPU einsum defaults to bf16)
+    hi = jax.lax.Precision.HIGHEST
+    r = jnp.einsum("bukn,un->bkn", h, t, precision=hi) + z
+    mf = jnp.einsum("bu,bukn->bkn", w.astype(jnp.complex64), h, precision=hi)
     y = jnp.sum(jnp.conj(mf) * r, axis=1)                     # [B, N]
     return jnp.real(y), jnp.imag(y)

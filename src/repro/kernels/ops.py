@@ -23,8 +23,16 @@ from repro.kernels.ota_combine import ota_combine, ota_combine_batched
 from repro.kernels.ref import ota_combine_ref, ota_combine_ref_batched
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret_mode() -> bool:
+    """How the Pallas kernels run on the default backend: compiled by
+    Mosaic on a TPU (False), interpreted on the CPU (True — the tests).
+    Any other backend is an error, never a silent interpreter run."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the Pallas kernels run compiled on 'tpu' or interpreted on "
+            f"'cpu'; the default backend is {backend!r}")
+    return backend == "cpu"
 
 
 def mf_combine(h: jax.Array, t: jax.Array, z: jax.Array,
@@ -47,7 +55,7 @@ def mf_combine(h: jax.Array, t: jax.Array, z: jax.Array,
     if use_kernel:
         fn = ota_combine_batched if batched else ota_combine
         y_re, y_im = fn(*args, block_n=block_n, block_k=block_k,
-                        interpret=not _on_tpu())
+                        interpret=interpret_mode())
     else:
         fn = ota_combine_ref_batched if batched else ota_combine_ref
         y_re, y_im = fn(*args)
@@ -76,5 +84,5 @@ def fused_combine(seed: jax.Array, t: jax.Array, amp: jax.Array,
                            sigma_h2=sigma_h2, sigma_z2=sigma_z2,
                            rx_base=rx_base, u_base=u_base, n_base=n_base,
                            block_n=block_n, block_k=block_k,
-                           block_u=block_u, interpret=not _on_tpu())
+                           block_u=block_u, interpret=interpret_mode())
     return jax.lax.complex(y_re, y_im)

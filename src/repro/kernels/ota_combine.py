@@ -26,6 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _combine_kernel(h_re_ref, h_im_ref, t_re_ref, t_im_ref, z_re_ref,
@@ -111,9 +112,8 @@ def ota_combine(h_re, h_im, t_re, t_im, z_re, z_im, w, *, block_n: int = 512,
         out_specs=y_spec,
         out_shape=jax.ShapeDtypeStruct((2, Np), jnp.float32),
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
     )(h_re, h_im, t_re, t_im, z_re, z_im, w[:, None].astype(jnp.float32))
     return y[0, :N], y[1, :N]
 
@@ -197,10 +197,8 @@ def ota_combine_batched(h_re, h_im, t_re, t_im, z_re, z_im, w, *,
         out_specs=y_spec,
         out_shape=jax.ShapeDtypeStruct((B, 2, Np), jnp.float32),
         interpret=interpret,
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel",
-                                             "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(h_re, h_im, t_re, t_im, z_re, z_im, w.astype(jnp.float32))
     return y[:, 0, :N], y[:, 1, :N]
 

@@ -11,15 +11,7 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
-from jax.sharding import Mesh
-
-# `AxisType` only exists on newer jax (>= 0.5); older installs get the
-# plain-Mesh behaviour (every axis implicitly Auto), which is what the
-# refinement needs anyway.
-try:
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -46,8 +38,6 @@ def refine_mesh(mesh, *, users_per_cluster: int = 4):
         raise ValueError(f"data axis {n_data} not divisible by M={M}")
     devs = devs.reshape(n_pod, n_data // M, M, n_model)
     names = ("pod", "cluster", "user", "model")
-    if AxisType is None:
-        return Mesh(devs, names)
     return Mesh(devs, names, axis_types=(AxisType.Auto,) * 4)
 
 

@@ -38,24 +38,14 @@ class Rules:
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names=None,
               check_vma: bool = False):
-    """Version-portable `shard_map`.
+    """`jax.shard_map` with the repo's default ``check_vma=False``.
 
-    Newer jax exposes `jax.shard_map(..., axis_names=, check_vma=)`;
-    older releases have `jax.experimental.shard_map.shard_map(...,
-    auto=, check_rep=)`.  `axis_names` is the set of *manual* axes; the
-    remaining mesh axes stay automatic on both APIs.
+    `axis_names` is the set of *manual* axes (default: all of them);
+    the remaining mesh axes stay automatic.
     """
-    if hasattr(jax, "shard_map"):
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = (frozenset(mesh.axis_names) - frozenset(axis_names)
-            if axis_names is not None else frozenset())
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, auto=auto)
+    kw = {} if axis_names is None else {"axis_names": axis_names}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 
 _state = threading.local()

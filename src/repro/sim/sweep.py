@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import functools
 import json
 import os
 import time
@@ -59,6 +60,7 @@ from repro.ft.guard import GUARD_POLICIES, validate_guard
 from repro.nn.core import split_params
 from repro.obs.telemetry import TELEMETRY_KEYS, summarize
 from repro.optim import adam, sgd
+from repro.sim.compile_cache import enable_compile_cache
 from repro.sim.scenario import Scenario, get_scenario, list_scenarios
 
 
@@ -137,6 +139,17 @@ class SweepResult:
             "exec": dict(self.exec_info),
             "telemetry": self.telemetry,
         }
+
+
+def _jit_with_data(build, X, Y, donate_argnums=()):
+    """``jax.jit`` of the program ``build(X, Y)`` returns, with the data
+    shards X, Y bound as leading arguments: closed over, they would be
+    compiled into the executable as constants (hundreds of MB at paper
+    size); as arguments they stay device buffers.  The result has the
+    program's own signature; `donate_argnums` index into it."""
+    fn = jax.jit(lambda X, Y, *args: build(X, Y)(*args),
+                 donate_argnums=tuple(i + 2 for i in donate_argnums))
+    return functools.partial(fn, jnp.asarray(X), jnp.asarray(Y))
 
 
 class _FTContext:
@@ -280,9 +293,10 @@ class SweepRunner:
                      X, Y, counter):
         """Build the seed-batched round executor
         ``(states, keys, P_t, P_is_t) -> states`` for one scenario."""
-        round_fn = make_round_fn(loss_fn, opt, topo, cfg, spec, X, Y,
-                                 trace_counter=counter)
-        return self._batch_round(round_fn)
+        return _jit_with_data(
+            lambda X, Y: self._batch_round_fn(make_round_fn(
+                loss_fn, opt, topo, cfg, spec, X, Y, trace_counter=counter)),
+            X, Y)
 
     def _batch_round_fn(self, round_fn):
         """Seed-batched round executor, unjitted (see class doc for
@@ -317,12 +331,14 @@ class SweepRunner:
         [S]-stacked states of the scale_u* scenarios the round state is
         the dominant allocation, and donation lets XLA reuse it across
         eval windows instead of holding two copies live."""
-        round_fn = make_round_fn(loss_fn, opt, topo, cfg, spec, X, Y,
-                                 trace_counter=counter)
-        chunk = make_chunk_fn(self._batch_round_fn(round_fn),
-                              self._batch_eval_fn(eval_fn),
-                              split_fn=jax.vmap(jax.random.split))
-        return jax.jit(chunk, donate_argnums=(0, 1))
+        def chunk(X, Y):
+            round_fn = make_round_fn(loss_fn, opt, topo, cfg, spec, X, Y,
+                                     trace_counter=counter)
+            return make_chunk_fn(self._batch_round_fn(round_fn),
+                                 self._batch_eval_fn(eval_fn),
+                                 split_fn=jax.vmap(jax.random.split))
+
+        return _jit_with_data(chunk, X, Y, donate_argnums=(0, 1))
 
     def _exec_info(self, topo=None, two_n=None) -> Dict:
         """Execution-engine metadata recorded with every result.
@@ -993,6 +1009,7 @@ def main(argv: Optional[Sequence[str]] = None) -> Dict:
                                          combine=args.combine)
                 except (KeyError, ValueError) as e:
                     ap.error(str(e.args[0] if e.args else e))
+                enable_compile_cache()
                 results.extend(runner.run())
     finally:
         if tracer is not None:

@@ -9,20 +9,9 @@ import subprocess
 import sys
 import textwrap
 
-import jax
 import pytest
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# Partial-auto shard_map (manual cluster/user axes + an auto model axis)
-# needs the jax>=0.6 `jax.shard_map(axis_names=...)` API; on older jax
-# the SPMD partitioner lowers `axis_index` to a PartitionId instruction
-# XLA:CPU cannot partition.  Fully-manual aggregation tests still run.
-requires_partial_auto = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-auto shard_map needs jax>=0.6 "
-           "(XLA:CPU PartitionId limitation)")
-
 
 def _run(script: str, n_dev: int = 8) -> str:
     env = dict(os.environ)
@@ -111,7 +100,6 @@ def test_equivalent_aggregation_unbiased_and_fused_matches():
     """)
 
 
-@requires_partial_auto
 def test_train_step_runs_and_learns():
     _run("""
     import jax
@@ -156,7 +144,6 @@ def test_train_step_runs_and_learns():
     """)
 
 
-@requires_partial_auto
 def test_local_sgd_tau_I_path():
     _run("""
     import jax

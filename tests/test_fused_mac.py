@@ -131,6 +131,40 @@ def test_canonical_block_u():
     assert canonical_block_u(4096, cap=512) == 512
 
 
+def test_m5_padded_u_blocks():
+    """The paper's cluster hop (C = 4 ISs hear U = C * M = 20 users,
+    M = 5, K = 100) with 8-user u-blocks: the kernel pads U to 24 with
+    amp = w = 0 users, which draw at their own counter indices (20..23)
+    and add exact zeros — bitwise the call on explicitly padded inputs,
+    even with nonzero symbols on the padded rows — and the result
+    agrees with the reference and with the canonical 5-user blocking."""
+    rng = np.random.default_rng(55)
+    B, U, K, N = 4, 20, 100, 300
+    t_re, t_im, amp, w = _mk(rng, B, U, N)
+    kw = dict(K=K, sigma_h2=1.0, sigma_z2=10.0)
+    y_re, y_im = fused_mac(SEED, t_re, t_im, amp, w, block_u=8,
+                           interpret=True, **kw)
+
+    junk = jnp.asarray(rng.standard_normal((2, 4, N)), jnp.float32)
+    zeros = jnp.zeros((B, 4), jnp.float32)
+    p_re, p_im = fused_mac(
+        SEED, jnp.concatenate([t_re, junk[0]]),
+        jnp.concatenate([t_im, junk[1]]),
+        jnp.concatenate([amp, zeros], axis=1),
+        jnp.concatenate([w, zeros], axis=1), block_u=8, interpret=True,
+        **kw)
+    np.testing.assert_array_equal(np.asarray(p_re), np.asarray(y_re))
+    np.testing.assert_array_equal(np.asarray(p_im), np.asarray(y_im))
+
+    rr, ri = fused_mac_ref(SEED, t_re, t_im, amp, w, **kw)
+    c_re, c_im = fused_mac(SEED, t_re, t_im, amp, w,
+                           block_u=canonical_block_u(5), interpret=True,
+                           **kw)
+    scale = float(jnp.abs(jax.lax.complex(rr, ri)).max())
+    for a, b in ((y_re, rr), (y_im, ri), (c_re, rr), (c_im, ri)):
+        assert float(jnp.abs(a - b).max()) / scale < 1e-4
+
+
 @pytest.mark.parametrize("U,K,n_tiles,N", [
     (32, 8, 2, 256),     # aligned, 2 u-tiles
     (60, 12, 4, 130),    # padded K (12 -> 16), unaligned N, 4 u-tiles

@@ -445,7 +445,7 @@ def test_trajectory_report_table(tmp_path):
          "records": [_traj_rec()]},                       # v1-style entry
         {"run_id": "new", "timestamp": "t1", "passed": True,
          "provenance": {"git_sha": "abcdef0123456789", "jax_version":
-                        "0.4.37", "device_count": 8, "platform": "x"},
+                        "0.9.0", "device_count": 8, "platform": "x"},
          "records": [_traj_rec()]},
     ]}
     table = trajectory_table(doc)
