@@ -42,6 +42,17 @@ if TYPE_CHECKING:   # annotation-only: repro.ft imports this layer
 
 CLUSTER_AGGREGATORS = ("mean", "median", "trimmed_mean")
 
+# The round's phases, named on the compiled program's ops with
+# `jax.named_scope` in both engines (metadata only: not one op
+# changes).  A profile, or the `op_name` of the compiled HLO, then ties
+# each device op to its phase: local training (the minibatch draw and
+# gather nested inside it as "whfl.batch"), the MU->IS cluster hop, the
+# hop into the PS (IS->PS, or MU->PS in conventional mode), the model
+# updates with the guard and power accounting, and the eval a chunk
+# folds in.
+SCOPES = ("whfl.train", "whfl.batch", "whfl.cluster_hop", "whfl.ps_hop",
+          "whfl.update", "whfl.eval")
+
 
 @dataclass(frozen=True)
 class WHFLConfig:
@@ -157,14 +168,17 @@ def make_local_train(loss_fn: Callable, opt: Optimizer,
         def body(carry, k):
             th, st = carry
             kb, kd = jax.random.split(k)
-            idx = jax.random.randint(kb, (cfg.batch,), 0, x.shape[0])
-            grads = jax.grad(loss_fn)(th, x[idx], y[idx], kd)
+            with jax.named_scope("whfl.batch"):
+                idx = jax.random.randint(kb, (cfg.batch,), 0, x.shape[0])
+                xb, yb = x[idx], y[idx]
+            grads = jax.grad(loss_fn)(th, xb, yb, kd)
             upd, st = opt.update(grads, st, th, step)
             return (apply_updates(th, upd), st), None
 
-        keys = jax.random.split(key, cfg.tau)
-        (th, st), _ = jax.lax.scan(body, (theta, opt_state), keys)
-        delta = jax.tree.map(lambda a, b: a - b, th, theta)
+        with jax.named_scope("whfl.train"):
+            keys = jax.random.split(key, cfg.tau)
+            (th, st), _ = jax.lax.scan(body, (theta, opt_state), keys)
+            delta = jax.tree.map(lambda a, b: a - b, th, theta)
         return delta, st
 
     return local_train
@@ -240,6 +254,7 @@ def make_round_fn(loss_fn: Callable, opt: Optimizer, topo: Topology,
     rx_w_conv = (np.ones((C, M), np.float32) if cfg.ota.mode == "ideal"
                  else np.asarray(topo.beta_mu_ps, np.float32))
 
+    @jax.named_scope("whfl.train")
     def users_train(theta_IS, opt_state, key, step):
         """theta_IS: [C]-stacked cluster models -> flat deltas [C,M,2N]."""
         keys = jax.random.split(key, C * M).reshape(C, M, 2)
@@ -282,25 +297,27 @@ def make_round_fn(loss_fn: Callable, opt: Optimizer, topo: Topology,
                 lambda x: jnp.broadcast_to(x, (C,) + x.shape), theta)
             k1, k2 = jax.random.split(key)
             flat, opt_state = users_train(theta_IS, state["opt"], k1, step)
-            if partial:
-                flat = agg.cotaf_precode(flat, mult)
-            est = conventional_ota(k2, maybe_poison(flat, step), topo,
-                                   P_t, cfg.ota)
-            if partial:
-                est = est * agg.attendance_rescale(
-                    rx_w_conv.reshape(-1), claimed.reshape(-1))
-            if guard_on:
-                est, g_trip = guard_estimate(est, cfg.guard)
-            theta = apply_updates(theta, agg.unflatten(spec, est))
-            p_edge = agg.symbol_power(flat, P_t)
-            out = {**state, "theta": theta, "opt": opt_state,
-                   "t": step + 1,
-                   "power_edge": state["power_edge"] + p_edge,
-                   "n_edge_tx": state["n_edge_tx"] + 1.0,
-                   "power_is": state["power_is"],
-                   "n_is_tx": state["n_is_tx"]}
-            if guard_on:
-                out["guard_trips"] = state["guard_trips"] + g_trip
+            with jax.named_scope("whfl.ps_hop"):
+                if partial:
+                    flat = agg.cotaf_precode(flat, mult)
+                est = conventional_ota(k2, maybe_poison(flat, step), topo,
+                                       P_t, cfg.ota)
+                if partial:
+                    est = est * agg.attendance_rescale(
+                        rx_w_conv.reshape(-1), claimed.reshape(-1))
+            with jax.named_scope("whfl.update"):
+                if guard_on:
+                    est, g_trip = guard_estimate(est, cfg.guard)
+                theta = apply_updates(theta, agg.unflatten(spec, est))
+                p_edge = agg.symbol_power(flat, P_t)
+                out = {**state, "theta": theta, "opt": opt_state,
+                       "t": step + 1,
+                       "power_edge": state["power_edge"] + p_edge,
+                       "n_edge_tx": state["n_edge_tx"] + 1.0,
+                       "power_is": state["power_is"],
+                       "n_is_tx": state["n_is_tx"]}
+                if guard_on:
+                    out["guard_trips"] = state["guard_trips"] + g_trip
             if tele_on:
                 out["telemetry"] = {
                     **cluster_telemetry(flat, est, claimed, topo, P_t,
@@ -317,20 +334,22 @@ def make_round_fn(loss_fn: Callable, opt: Optimizer, topo: Topology,
             g_acc = carry[3] if guard_on else None
             k1, k2 = jax.random.split(k)
             flat, opt_state = users_train(th_IS, opt_state, k1, step)
-            if partial:
-                flat = agg.cotaf_precode(flat, mult)
-            est = cluster_fold(k2, maybe_poison(flat, step), claimed,
-                               P_t)                         # [C, 2N]
-            if guard_on:
-                est, g_trip = guard_estimate(est, cfg.guard)
-                g_acc = g_acc + g_trip
-            th_IS = jax.vmap(
-                lambda th, e: apply_updates(th, agg.unflatten(spec, e))
-            )(th_IS, est)
-            out = (th_IS, opt_state,
-                   p_acc + agg.symbol_power(flat, P_t))
-            if guard_on:
-                out += (g_acc,)
+            with jax.named_scope("whfl.cluster_hop"):
+                if partial:
+                    flat = agg.cotaf_precode(flat, mult)
+                est = cluster_fold(k2, maybe_poison(flat, step), claimed,
+                                   P_t)                     # [C, 2N]
+            with jax.named_scope("whfl.update"):
+                if guard_on:
+                    est, g_trip = guard_estimate(est, cfg.guard)
+                    g_acc = g_acc + g_trip
+                th_IS = jax.vmap(
+                    lambda th, e: apply_updates(th, agg.unflatten(spec, e))
+                )(th_IS, est)
+                out = (th_IS, opt_state,
+                       p_acc + agg.symbol_power(flat, P_t))
+                if guard_on:
+                    out += (g_acc,)
             if tele_on:
                 # the last cluster iteration's block survives
                 out += (cluster_telemetry(flat, est, claimed, topo, P_t),)
@@ -347,21 +366,25 @@ def make_round_fn(loss_fn: Callable, opt: Optimizer, topo: Topology,
         g_edge = carry[3] if guard_on else None
         tele_blk = carry[3 + int(guard_on)] if tele_on else None
 
-        is_deltas = jax.vmap(
-            lambda th: agg.flatten(
-                spec, jax.tree.map(lambda a, b: a - b, th, theta)))(theta_IS)
-        est = global_ota(keys[-1], is_deltas, topo, P_is_t, cfg.ota)
-        if guard_on:
-            est, g_is = guard_estimate(est, cfg.guard)
-        theta = apply_updates(theta, agg.unflatten(spec, est))
-        p_is = agg.symbol_power(is_deltas, P_is_t)
-        out = {**state, "theta": theta, "opt": opt_state, "t": step + 1,
-               "power_edge": state["power_edge"] + p_edge,
-               "n_edge_tx": state["n_edge_tx"] + float(cfg.I),
-               "power_is": state["power_is"] + p_is,
-               "n_is_tx": state["n_is_tx"] + 1.0}
-        if guard_on:
-            out["guard_trips"] = state["guard_trips"] + g_edge + g_is
+        with jax.named_scope("whfl.ps_hop"):
+            is_deltas = jax.vmap(
+                lambda th: agg.flatten(
+                    spec, jax.tree.map(lambda a, b: a - b, th, theta)))(
+                        theta_IS)
+            est = global_ota(keys[-1], is_deltas, topo, P_is_t, cfg.ota)
+        with jax.named_scope("whfl.update"):
+            if guard_on:
+                est, g_is = guard_estimate(est, cfg.guard)
+            theta = apply_updates(theta, agg.unflatten(spec, est))
+            p_is = agg.symbol_power(is_deltas, P_is_t)
+            out = {**state, "theta": theta, "opt": opt_state,
+                   "t": step + 1,
+                   "power_edge": state["power_edge"] + p_edge,
+                   "n_edge_tx": state["n_edge_tx"] + float(cfg.I),
+                   "power_is": state["power_is"] + p_is,
+                   "n_is_tx": state["n_is_tx"] + 1.0}
+            if guard_on:
+                out["guard_trips"] = state["guard_trips"] + g_edge + g_is
         if tele_on:
             out["telemetry"] = {**tele_blk,
                                 **is_telemetry(is_deltas, topo, P_is_t)}
@@ -431,7 +454,10 @@ def make_chunk_fn(round_fn: Callable, eval_fn: Optional[Callable] = None,
 
         (state, keys), _ = jax.lax.scan(body, (state, keys),
                                         (P_win, P_is_win))
-        metrics = eval_fn(state) if eval_fn is not None else None
+        metrics = None
+        if eval_fn is not None:
+            with jax.named_scope("whfl.eval"):
+                metrics = eval_fn(state)
         return state, keys, metrics
 
     return chunk_fn

@@ -233,6 +233,7 @@ def _build_round_parts(loss_fn: Callable, opt: Optimizer, topo: Topology,
             lambda x: jax.lax.dynamic_slice_in_dim(x, ci * C_loc, C_loc, 0),
             tree)
 
+    @jax.named_scope("whfl.train")
     def users_train(theta_IS, opt_loc, key, step, X_loc, Y_loc, ci, ui,
                     mult_p=None):
         """Local training of this shard's users.
@@ -279,7 +280,9 @@ def _build_round_parts(loss_fn: Callable, opt: Optimizer, topo: Topology,
                 flat = agg.flatten(spec, delta)
                 if partial:
                     flat = flat * m
-                return flat, st, agg.user_energy(flat)
+                with jax.named_scope("whfl.update"):   # power accounting
+                    energy = agg.user_energy(flat)
+                return flat, st, energy
 
             xs = ((opt_c, x_c, y_c, k_c, m_c) if partial
                   else (opt_c, x_c, y_c, k_c))
@@ -472,24 +475,27 @@ def _build_round_parts(loss_fn: Callable, opt: Optimizer, topo: Topology,
             flat_loc, opt_state, pw = users_train(
                 theta_IS, state["opt"], k1, step, X_loc, Y_loc, ci, ui,
                 mult_p)
-            flat = _gather_cm(flat_loc)
-            est = conventional_ota(
-                k2, _gather_cm(maybe_poison_loc(flat_loc, step, ci, ui))
-                if poison is not None else flat, topo, P_t, cfg.ota)
-            if partial:
-                est = est * agg.attendance_rescale(
-                    rx_w_conv.reshape(-1), claimed.reshape(-1))
-            if guard_on:
-                est, g_trip = guard_estimate(est, cfg.guard)
-            theta = apply_updates(theta, agg.unflatten(spec, est))
-            out = {**state, "theta": theta, "opt": opt_state,
-                   "t": step + 1,
-                   "power_edge": state["power_edge"] + edge_power(pw, P_t),
-                   "n_edge_tx": state["n_edge_tx"] + 1.0,
-                   "power_is": state["power_is"],
-                   "n_is_tx": state["n_is_tx"]}
-            if guard_on:
-                out["guard_trips"] = state["guard_trips"] + g_trip
+            with jax.named_scope("whfl.ps_hop"):
+                flat = _gather_cm(flat_loc)
+                est = conventional_ota(
+                    k2, _gather_cm(maybe_poison_loc(flat_loc, step, ci, ui))
+                    if poison is not None else flat, topo, P_t, cfg.ota)
+                if partial:
+                    est = est * agg.attendance_rescale(
+                        rx_w_conv.reshape(-1), claimed.reshape(-1))
+            with jax.named_scope("whfl.update"):
+                if guard_on:
+                    est, g_trip = guard_estimate(est, cfg.guard)
+                theta = apply_updates(theta, agg.unflatten(spec, est))
+                out = {**state, "theta": theta, "opt": opt_state,
+                       "t": step + 1,
+                       "power_edge": (state["power_edge"]
+                                      + edge_power(pw, P_t)),
+                       "n_edge_tx": state["n_edge_tx"] + 1.0,
+                       "power_is": state["power_is"],
+                       "n_is_tx": state["n_is_tx"]}
+                if guard_on:
+                    out["guard_trips"] = state["guard_trips"] + g_trip
             if tele_on:
                 out["telemetry"] = {
                     **cluster_telemetry(flat, est, claimed, topo, P_t,
@@ -504,18 +510,20 @@ def _build_round_parts(loss_fn: Callable, opt: Optimizer, topo: Topology,
             k1, k2 = jax.random.split(k)
             flat_loc, opt_state, pw = users_train(
                 th_IS, opt_state, k1, step, X_loc, Y_loc, ci, ui, mult_p)
-            est = cluster_estimate(
-                k2, maybe_poison_loc(flat_loc, step, ci, ui), P_t, ci,
-                ui, claimed)                                 # [Cp, 2N]
-            if guard_on:
-                est, g_trip = guard_estimate(est, cfg.guard)
-                g_acc = g_acc + g_trip
-            th_IS = jax.vmap(
-                lambda th, e: apply_updates(th, agg.unflatten(spec, e))
-            )(th_IS, est)
-            out = (th_IS, opt_state, p_acc + edge_power(pw, P_t))
-            if guard_on:
-                out += (g_acc,)
+            with jax.named_scope("whfl.cluster_hop"):
+                est = cluster_estimate(
+                    k2, maybe_poison_loc(flat_loc, step, ci, ui), P_t, ci,
+                    ui, claimed)                             # [Cp, 2N]
+            with jax.named_scope("whfl.update"):
+                if guard_on:
+                    est, g_trip = guard_estimate(est, cfg.guard)
+                    g_acc = g_acc + g_trip
+                th_IS = jax.vmap(
+                    lambda th, e: apply_updates(th, agg.unflatten(spec, e))
+                )(th_IS, est)
+                out = (th_IS, opt_state, p_acc + edge_power(pw, P_t))
+                if guard_on:
+                    out += (g_acc,)
             if tele_on:
                 # the last cluster iteration's block survives
                 # gathered real [C, M, 2N] deltas + real estimate rows:
@@ -538,24 +546,27 @@ def _build_round_parts(loss_fn: Callable, opt: Optimizer, topo: Topology,
         tele_blk = carry[3 + int(guard_on)] if tele_on else None
 
         # only the real clusters transmit to the PS
-        theta_IS_act = (theta_IS if Cp == C else
-                        jax.tree.map(lambda x: x[:C], theta_IS))
-        is_deltas = jax.vmap(
-            lambda th: agg.flatten(
-                spec,
-                jax.tree.map(lambda a, b: a - b, th, theta)))(theta_IS_act)
-        est = global_ota(keys[-1], is_deltas, topo, P_is_t, cfg.ota)
-        if guard_on:
-            est, g_is = guard_estimate(est, cfg.guard)
-        theta = apply_updates(theta, agg.unflatten(spec, est))
-        p_is = agg.symbol_power(is_deltas, P_is_t)
-        out = {**state, "theta": theta, "opt": opt_state, "t": step + 1,
-               "power_edge": state["power_edge"] + p_edge,
-               "n_edge_tx": state["n_edge_tx"] + float(cfg.I),
-               "power_is": state["power_is"] + p_is,
-               "n_is_tx": state["n_is_tx"] + 1.0}
-        if guard_on:
-            out["guard_trips"] = state["guard_trips"] + g_edge + g_is
+        with jax.named_scope("whfl.ps_hop"):
+            theta_IS_act = (theta_IS if Cp == C else
+                            jax.tree.map(lambda x: x[:C], theta_IS))
+            is_deltas = jax.vmap(
+                lambda th: agg.flatten(
+                    spec, jax.tree.map(lambda a, b: a - b, th, theta)))(
+                        theta_IS_act)
+            est = global_ota(keys[-1], is_deltas, topo, P_is_t, cfg.ota)
+        with jax.named_scope("whfl.update"):
+            if guard_on:
+                est, g_is = guard_estimate(est, cfg.guard)
+            theta = apply_updates(theta, agg.unflatten(spec, est))
+            p_is = agg.symbol_power(is_deltas, P_is_t)
+            out = {**state, "theta": theta, "opt": opt_state,
+                   "t": step + 1,
+                   "power_edge": state["power_edge"] + p_edge,
+                   "n_edge_tx": state["n_edge_tx"] + float(cfg.I),
+                   "power_is": state["power_is"] + p_is,
+                   "n_is_tx": state["n_is_tx"] + 1.0}
+            if guard_on:
+                out["guard_trips"] = state["guard_trips"] + g_edge + g_is
         if tele_on:
             out["telemetry"] = {**tele_blk,
                                 **is_telemetry(is_deltas, topo, P_is_t)}
@@ -654,7 +665,10 @@ def make_sharded_chunk_fn(loss_fn: Callable, opt: Optimizer, topo: Topology,
         state, key = sharded(state, key,
                              jnp.asarray(P_win, jnp.float32),
                              jnp.asarray(P_is_win, jnp.float32), X, Y)
-        metrics = eval_fn(state) if eval_fn is not None else None
+        metrics = None
+        if eval_fn is not None:
+            with jax.named_scope("whfl.eval"):
+                metrics = eval_fn(state)
         return state, key, metrics
 
     return chunk_fn

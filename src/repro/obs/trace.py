@@ -17,7 +17,11 @@ trustworthy), plus event-specific fields:
   execution + metric fetch; chunked windows carry
   ``enqueue_only: true`` — the chunked driver is asynchronous by
   design (one device sync per scenario), so the per-window number is
-  enqueue latency, not execution time.
+  the host's time in the chunk call, not execution time.  Where the
+  runtime's queue of executions is full, that call waits for room, so
+  the number includes the wait; a ``--profile`` trace puts each
+  call's ``sweep.dispatch`` span beside the device's ops, which shows
+  how much of it was that wait.
 - ``telemetry`` — per-eval-window scalar summary of the in-program
   telemetry block (`repro.obs.telemetry.summarize`), emitted when the
   scenario ran with ``telemetry=True``.

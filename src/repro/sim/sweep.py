@@ -28,7 +28,11 @@ engine/driver metadata); `--telemetry` records the in-program
 physical-layer diagnostics block (`repro.obs.telemetry` — off by
 default, and off is a bitwise no-op), `--trace` journals the run as
 `repro.obs.trace/v1` JSONL, and `--profile DIR` wraps the sweep in
-``jax.profiler.trace``.
+``jax.profiler.trace``.  On the profiler's clock the drivers mark their
+host work as spans: ``sweep.dispatch`` (each chunk or round call),
+``sweep.fetch`` (metric fetches), ``sweep.checkpoint`` (each save) and
+``sweep.guard`` (each guard check); the round program names its phases
+(`repro.core.whfl.SCOPES`).
 
 Output is a structured JSON document (`SCHEMA_VERSION`), and
 `csv_lines` renders the benchmark-suite CSV convention
@@ -492,7 +496,8 @@ class SweepRunner:
                         ckpt_every=self.ckpt_every,
                         start_round=start_round,
                         windows_done=windows_done, faults=self.faults,
-                        save=save_ckpt)
+                        save=jax.profiler.annotate_function(
+                            save_ckpt, name="sweep.checkpoint"))
 
         def check_guard(state_now, round_idx):
             total = int(np.sum(np.asarray(state_now["guard_trips"])))
@@ -503,7 +508,8 @@ class SweepRunner:
             if ft.guard_halt and total > 0:
                 ft.halted = True
 
-        ft.check_guard = check_guard
+        ft.check_guard = jax.profiler.annotate_function(
+            check_guard, name="sweep.guard")
 
         if self.driver == "chunked":
             state, dispatches, drive_s = self._drive_chunked(
@@ -588,21 +594,23 @@ class SweepRunner:
             P_t, P_is_t = power_schedule(
                 t, cfg.power_base, cfg.power_slope, cfg.power_is_factor,
                 cfg.power_low)
-            ks = split_b(keys)
-            keys, subs = ks[:, 0], ks[:, 1]
-            state = round_b(state, subs, P_t, P_is_t)
+            with jax.profiler.TraceAnnotation("sweep.dispatch"):
+                ks = split_b(keys)
+                keys, subs = ks[:, 0], ks[:, 1]
+                state = round_b(state, subs, P_t, P_is_t)
             dispatches += 2
             win_rounds += 1
             if t % sc.eval_every == 0 or t == T - 1:
-                accs, losses = eval_b(state["theta"])
+                with jax.profiler.TraceAnnotation("sweep.fetch"):
+                    accs, losses = eval_b(state["theta"])
+                    accs, losses = np.asarray(accs), np.asarray(losses)
+                    pe = np.asarray(state["power_edge"]
+                                    / jnp.maximum(state["n_edge_tx"], 1.0))
+                    pi = np.asarray(state["power_is"]
+                                    / jnp.maximum(state["n_is_tx"], 1.0))
+                    tele = (jax.device_get(state["telemetry"]) if tele_on
+                            else None)
                 dispatches += 1
-                accs, losses = np.asarray(accs), np.asarray(losses)
-                pe = np.asarray(state["power_edge"]
-                                / jnp.maximum(state["n_edge_tx"], 1.0))
-                pi = np.asarray(state["power_is"]
-                                / jnp.maximum(state["n_is_tx"], 1.0))
-                tele = (jax.device_get(state["telemetry"]) if tele_on
-                        else None)
                 rounds.append(t + 1)
                 record(accs, losses, pe, pi, tele)
                 self._note_traces(counter, seen)
@@ -700,24 +708,30 @@ class SweepRunner:
 
             def drain():
                 nonlocal pending
-                for metrics in jax.device_get(pending):
-                    record(*metrics)
+                with jax.profiler.TraceAnnotation("sweep.fetch"):
+                    for metrics in jax.device_get(pending):
+                        record(*metrics)
                 pending = []
 
             for w in windows[skip:]:
                 w_t0 = time.perf_counter()
-                state, keys, metrics = chunk_b(state, keys,
-                                               P_all[off:off + w],
-                                               P_is_all[off:off + w])
+                with jax.profiler.TraceAnnotation("sweep.dispatch"):
+                    state, keys, metrics = chunk_b(state, keys,
+                                                   P_all[off:off + w],
+                                                   P_is_all[off:off + w])
                 off += w
                 rounds.append(off)
                 pending.append(metrics)
                 driven += 1
                 windows_done += 1
                 self._note_traces(counter, seen)
-                # enqueue latency only: this driver is async by design
-                # (one device sync per scenario), so execution time is
-                # not observable per window
+                # the host's side of the window only: this driver is
+                # async by design (one device sync per scenario), so
+                # execution time is not observable per window.  Where
+                # the runtime's queue of executions is full, the call
+                # waits for room, so this includes that wait; a
+                # --profile trace puts the call's `sweep.dispatch` span
+                # beside the device's ops, which shows how much it was
                 self._emit("window", scenario=sc.name, round=off,
                            rounds=w, enqueue_only=True,
                            seconds=round(time.perf_counter() - w_t0, 6))
@@ -900,7 +914,9 @@ def main(argv: Optional[Sequence[str]] = None) -> Dict:
                          "window timings, telemetry summaries) here")
     ap.add_argument("--profile", default=None, metavar="DIR",
                     help="wrap the sweep in jax.profiler.trace(DIR) "
-                         "(view with TensorBoard / xprof)")
+                         "(view with TensorBoard / xprof); host spans "
+                         "sweep.dispatch / fetch / checkpoint / guard "
+                         "and the round's whfl.* scopes name its time")
     ap.add_argument("--checkpoint", default=None, metavar="DIR",
                     help="checkpoint the full sweep carry (stacked "
                          "trainer states, opt state, PRNG keys, metric "
