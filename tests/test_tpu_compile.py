@@ -51,10 +51,13 @@ def _compile(kernel, sharding, B, U, K, N, **kw):
 @pytest.mark.parametrize("name,B,U,K,M", [
     ("fig2_faithful", 4, 20, 100, 5),       # C=4 ISs hear C*M=20 users
     ("scale_u1024", 8, 1024, 16, 128),
+    ("fig2_ps_hop", 1, 4, 100, None),       # the PS hears C=4 ISs
 ])
 def test_fused_mac_compiles_for_v5e(one_chip, name, B, U, K, M):
+    """M=None: the default u-block, as the IS->PS hop calls the kernel."""
+    blocking = {} if M is None else dict(block_u=canonical_block_u(M))
     text = _compile(fused_mac, one_chip, B, U, K, N_MNIST, sigma_z2=1.0,
-                    block_u=canonical_block_u(M))
+                    **blocking)
     assert "tpu_custom_call" in text, name
 
 
