@@ -13,7 +13,7 @@ models initialised at random from seeds and synthetic data:
   fig2_iid_fused  the same, with the faithful OTA hops through the fused
                   Pallas kernel (M=5, K=100)
   scale_u1024     C=8, M=128, K=16, fused kernel, 2 rounds
-  fig3_cifar      the 307,498-parameter CNN, tau=5, batch 128, 2 rounds
+  fig3_cifar      the 308,394-parameter CNN, tau=5, batch 128, 2 rounds
 
 Each phase prints one JSON line with its scenario, metrics, seconds and
 the seconds spent compiling.  Every phase checks that its metrics are

@@ -2,7 +2,9 @@
 
 - MNIST: single-layer network, 784 -> 10 (2N = 7850 params incl. bias).
 - CIFAR-10: CNN with conv pairs 32/64/128 (3x3, same padding) + BN + ReLU,
-  2x2 max-pool + dropout after each pair, FC softmax head (2N = 307,498).
+  2x2 max-pool + dropout after each pair, FC softmax head (2N = 308,394:
+  307,498 conv and FC weights and biases plus 896 batch-norm scales and
+  biases).
 
 Pure JAX init/apply in the same Px convention as the big models.
 """
@@ -66,9 +68,12 @@ def cifar_init(key):
 
 
 def _conv_bn_relu(p, x):
-    y = jax.lax.conv_general_dilated(
-        x, p["w"], window_strides=(1, 1), padding="SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC")) + p["b"]
+    # the convolution and its bias add carry the `cnn.conv` scope (their
+    # backward ops inherit it), so a trace can tell the convs' time
+    with jax.named_scope("cnn.conv"):
+        y = jax.lax.conv_general_dilated(
+            x, p["w"], window_strides=(1, 1), padding="SAME",
+            dimension_numbers=("NHWC", "HWIO", "NHWC")) + p["b"]
     mu = y.mean(axis=(0, 1, 2))
     var = y.var(axis=(0, 1, 2))
     y = (y - mu) * jax.lax.rsqrt(var + 1e-5)

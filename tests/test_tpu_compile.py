@@ -16,6 +16,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import canonical_block_u, fused_mac, fused_mac_partials
 
 N_MNIST = 3925        # complex symbols of the 7,850-parameter MNIST MLP
+N_CIFAR = 154197      # complex symbols of the 308,394-parameter CIFAR CNN
 
 
 @pytest.fixture(scope="module")
@@ -57,6 +58,18 @@ def test_fused_mac_compiles_for_v5e(one_chip, name, B, U, K, M):
     """M=None: the default u-block, as the IS->PS hop calls the kernel."""
     blocking = {} if M is None else dict(block_u=canonical_block_u(M))
     text = _compile(fused_mac, one_chip, B, U, K, N_MNIST, sigma_z2=1.0,
+                    **blocking)
+    assert "tpu_custom_call" in text, name
+
+
+@pytest.mark.parametrize("name,B,U,M", [
+    ("fig3_faithful", 4, 20, 5),            # 302 symbol blocks of 512
+    ("fig3_ps_hop", 1, 4, None),
+])
+def test_fused_mac_compiles_for_v5e_at_cifar_width(one_chip, name, B, U, M):
+    """The CNN's hops (K = K_ps = 100), at the paper's largest N."""
+    blocking = {} if M is None else dict(block_u=canonical_block_u(M))
+    text = _compile(fused_mac, one_chip, B, U, 100, N_CIFAR, sigma_z2=1.0,
                     **blocking)
     assert "tpu_custom_call" in text, name
 
