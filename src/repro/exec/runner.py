@@ -144,11 +144,12 @@ class ShardedSweepRunner(SweepRunner):
 
         return jax.jit(batched, donate_argnums=(0, 1))
 
-    def _exec_info(self, topo=None, two_n=None) -> Dict:
+    def _exec_info(self, topo=None, two_n=None, n_user=None) -> Dict:
         mc, mu = self.mesh_shape
-        info = {"name": "sharded", "mesh": f"{mc}x{mu}",
-                "device_count": mc * mu, "batch": self.batch,
-                "padded": None, "combine": self.combine}
+        info = super()._exec_info(n_user=n_user)
+        info.update(name="sharded", mesh=f"{mc}x{mu}",
+                    device_count=mc * mu, padded=None,
+                    combine=self.combine)
         if topo is not None:
             plan = self._pad_plan(topo)
             if not plan.is_identity:

@@ -56,8 +56,8 @@ import numpy as np
 
 from repro.core import aggregation as agg
 from repro.core.topology import power_schedule
-from repro.core.whfl import (eval_windows, init_round_state, make_chunk_fn,
-                             make_round_fn)
+from repro.core.whfl import (eval_windows, init_round_state, label_lookup,
+                             make_chunk_fn, make_round_fn)
 from repro.ft import ckpt as ft_ckpt
 from repro.ft.faults import FaultPlan, hard_crash
 from repro.ft.guard import GUARD_POLICIES, validate_guard
@@ -344,15 +344,20 @@ class SweepRunner:
 
         return _jit_with_data(chunk, X, Y, donate_argnums=(0, 1))
 
-    def _exec_info(self, topo=None, two_n=None) -> Dict:
+    def _exec_info(self, topo=None, two_n=None, n_user=None) -> Dict:
         """Execution-engine metadata recorded with every result.
         `device_count` is the number of devices the engine *uses* (not
         how many are visible): always 1 for the single-device engine.
         `topo`/`two_n` (when given) let engines record
         workload-dependent metadata — the sharded engine reports its
-        padded shape and per-device peak symbol-block bytes."""
-        return {"name": "single", "mesh": None,
+        padded shape and per-device peak symbol-block bytes.
+        `n_user` (samples in a user's shard) adds the minibatch label
+        lookup local training uses (`repro.core.whfl.label_lookup`)."""
+        info = {"name": "single", "mesh": None,
                 "device_count": 1, "batch": self.batch}
+        if n_user is not None:
+            info["label_lookup"] = label_lookup(n_user)
+        return info
 
     # -- one scenario, all seeds at once ------------------------------------
 
@@ -360,6 +365,7 @@ class SweepRunner:
         t0 = time.perf_counter()
         init_fn, apply_fn, loss_fn = sc.task_fns()
         X, Y, xte, yte = sc.make_data()
+        n_user = X.shape[2]
         topo = sc.make_topology()
         cfg = sc.whfl_config()
         # runner-level fault-tolerance knobs rewrite the round config:
@@ -373,7 +379,7 @@ class SweepRunner:
         self._emit("scenario_start", scenario=sc.name,
                    seeds=len(self.seeds), rounds=sc.rounds,
                    driver=self.driver, telemetry=cfg.telemetry,
-                   exec_info=self._exec_info(topo))
+                   exec_info=self._exec_info(topo, n_user=n_user))
 
         # Stacked per-seed state: identical-by-construction to S
         # independent `init_state` calls.
@@ -470,7 +476,7 @@ class SweepRunner:
                 "seeds": list(self.seeds), "round": int(cursor),
                 "rounds_total": int(T), "git_sha": git_sha,
                 "jax_version": jax.__version__,
-                "engine": {**self._exec_info(topo),
+                "engine": {**self._exec_info(topo, n_user=n_user),
                            "driver": self.driver},
                 "guard": cfg.guard, "telemetry": bool(cfg.telemetry),
                 "eval": {
@@ -532,7 +538,8 @@ class SweepRunner:
                 self._emit("telemetry", scenario=sc.name, round=rd,
                            summary=summarize(t))
 
-        exec_info = {**self._exec_info(topo, two_n=spec.two_n),
+        exec_info = {**self._exec_info(topo, two_n=spec.two_n,
+                                       n_user=n_user),
                      "driver": self.driver,
                      "dispatches": dispatches, "drive_seconds": drive_s,
                      "warmup": self.warmup}

@@ -104,7 +104,7 @@ def test_scale_u256_sharded_1x1_vs_2x4_bitwise_and_seed_slice():
     assert ex == {"name": "sharded", "mesh": "2x4", "device_count": 8,
                   "batch": "map", "driver": "stepwise", "padded": None,
                   "combine": "gathered", "dispatches": 2 * 2 + 2,
-                  "warmup": False}
+                  "warmup": False, "label_lookup": "dense"}
     print("OK")
     """)
 
