@@ -8,19 +8,23 @@ Per global round t:
   - ISs OTA-transmit their accumulated deltas to the PS, which closes
     the round (eqs. 15-18).
 
-The whole round is one *pure* jitted function of
-``(state, key, P_t, P_is_t)`` built by `make_round_fn`; MU training is
-vmapped over (cluster, user), and the round itself can be vmapped over
-a leading seed axis (stacked states + per-seed keys) without
-re-tracing — this is what `repro.sim.SweepRunner` does to run S seeds
-in one compilation.  Baselines: `mode="conventional"` (single-hop OTA
+The round is written once, in `make_round_body`, for both execution
+engines: each supplies only its `Engine` parts (how users train and
+send, the cluster-hop estimate, the edge-power fold).  The single
+engine's round, `make_round_fn`, is one *pure* jitted function of
+``(state, key, P_t, P_is_t)``; MU training is vmapped over (cluster,
+user), and the round itself can be vmapped over a leading seed axis
+(stacked states + per-seed keys) without re-tracing — this is what
+`repro.sim.SweepRunner` does to run S seeds in one compilation.  The
+sharded engine (`repro.exec.round`) runs the same body under
+`shard_map`.  Baselines: `mode="conventional"` (single-hop OTA
 FL, the paper's main comparison) and `OTAConfig(mode="ideal")`
 (error-free).
 """
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import TYPE_CHECKING, Callable, Optional
+from typing import TYPE_CHECKING, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -221,27 +225,61 @@ def make_local_train(loss_fn: Callable, opt: Optimizer,
     return local_train
 
 
-def make_round_fn(loss_fn: Callable, opt: Optimizer, topo: Topology,
-                  cfg: WHFLConfig, spec: agg.FlatSpec, X, Y,
-                  trace_counter: Optional[list] = None) -> Callable:
-    """Build the pure per-round function ``round_fn(state, key, P_t,
-    P_is_t) -> state``.
+class Engine(NamedTuple):
+    """The parts of a W-HFL round that an execution engine supplies to
+    the one round body (`make_round_body`), which runs everything else.
 
-    Everything static (data shards, topology geometry, config, flat
-    spec) is closed over; the returned function touches no mutable
-    state, so it can be wrapped in `jax.jit` once and additionally
-    lifted with `jax.vmap` over a leading seed axis of ``(state, key)``
-    — S seeds share one trace/compile.
+    ``tx`` and ``sent`` are the engine's own forms of what its users
+    trained and what they transmitted; only these parts read them.
+
+    - ``train(theta_IS, opt, key, step, mult) -> (tx, opt)``: local
+      training of every user from its cluster's row of ``theta_IS``;
+      ``mult`` is the round's COTAF multipliers ``[C, M]``, or None
+      under full attendance.
+    - ``send(tx, mult) -> sent``: hand the users' deltas to a hop, COTAF
+      precoding included.
+    - ``cluster(key, sent, step, claimed, P_t) -> est``: the cluster
+      hop's estimate ``[rows, 2N]``, from the poison to the fold (the
+      attendance rescale is the body's).
+    - ``edge_power(sent, P_t)``: the transmissions' per-symbol power.
+    - ``flat(sent)``: the real users' transmitted ``[C, M, 2N]`` symbols,
+      which the MU->PS hop and the telemetry read.
+    """
+    train: Optional[Callable]
+    send: Callable
+    cluster: Callable
+    edge_power: Callable
+    flat: Callable
+
+
+def make_round_body(topo: Topology, cfg: WHFLConfig, spec: agg.FlatSpec,
+                    rows: Optional[int] = None,
+                    trace_counter: Optional[list] = None):
+    """The one W-HFL round both execution engines run.
+
+    Returns ``(body, parts)``.  ``body(engine, state, key, P_t, P_is_t)
+    -> state`` runs one round with an `Engine`'s parts: the
+    participation, robustness, telemetry and fault-tolerance gates, the
+    conventional MU->PS hop, the scan over `cfg.I` cluster iterations
+    with its guard and telemetry carries, the IS->PS hop, the updates,
+    the power and transmission counters and the telemetry block, each
+    phase under its `SCOPES` name.  ``parts`` are the `Engine` parts of
+    an engine whose users' deltas are the real ``[C, M, 2N]`` flat on
+    every device, its ``train`` left for the engine to fill: precode,
+    poison, the OTA or robust fold (`cluster_fold`), ``est`` padded to
+    `rows`, and `agg.symbol_power`.
+
+    `rows` is the number of cluster rows ``theta_IS`` is carried in:
+    C (the default), or a mesh's padded Cp, whose extra rows the body
+    drops before the PS hop and the telemetry.
 
     `trace_counter`, when given, is a list whose first element is
-    incremented every time the function is *traced* (not executed) —
+    incremented every time the round is *traced* (not executed) —
     tests use it to assert the one-compilation property of the sweep
     engine.
     """
     C, M = topo.C, topo.M
-    X = jnp.asarray(X)
-    Y = jnp.asarray(Y)
-    local_train = make_local_train(loss_fn, opt, cfg)
+    rows = C if rows is None else rows
 
     # Participation / robustness gates are PYTHON-level: a full schedule
     # with the mean fold traces the literally identical round program as
@@ -291,34 +329,36 @@ def make_round_fn(loss_fn: Callable, opt: Optimizer, topo: Topology,
     rx_w_conv = (np.ones((C, M), np.float32) if cfg.ota.mode == "ideal"
                  else np.asarray(topo.beta_mu_ps, np.float32))
 
-    @jax.named_scope("whfl.train")
-    def users_train(theta_IS, opt_state, key, step):
-        """theta_IS: [C]-stacked cluster models -> flat deltas [C,M,2N]."""
-        keys = jax.random.split(key, C * M).reshape(C, M, 2)
-        train_u = lambda th, st, x, y, k: local_train(th, st, x, y, k, step)
-        train_c = jax.vmap(train_u, in_axes=(None, 0, 0, 0, 0))
-        deltas, opt_state = jax.vmap(train_c)(theta_IS, opt_state, X, Y,
-                                              keys)
-        flat = jax.vmap(jax.vmap(lambda d: agg.flatten(spec, d)))(deltas)
-        return flat, opt_state
+    def real_rows(x):
+        """[rows, ...] -> the real clusters' [C, ...] rows."""
+        return x if rows == C else x[:C]
 
-    def cluster_fold(k2, flat, claimed, P_t):
+    def send(flat, mult):
+        return agg.cotaf_precode(flat, mult) if partial else flat
+
+    def cluster_fold(k2, flat, step, claimed, P_t):
         """Cluster-hop receive fold: the paper's OTA superposition mean
-        (with COTAF attendance rescale under partial participation) or
-        a robust masked fold over orthogonalized per-user receptions."""
+        or a robust masked fold over orthogonalized per-user receptions,
+        its inactive rows (``rows > C``) exactly zero."""
+        flat = maybe_poison(flat, step)
         if robust:
             mask = (claimed if partial
                     else jnp.ones((C, M), jnp.float32))
             per_user = orthogonal_cluster_ota(k2, flat, topo, P_t, cfg.ota)
             if cfg.cluster_agg == "median":
-                return agg.masked_median(per_user, mask)
-            return agg.masked_trimmed_mean(per_user, mask, cfg.agg_trim)
-        est = cluster_ota(k2, flat, topo, P_t, cfg.ota)  # [C, 2N]
-        if partial:
-            est = est * agg.attendance_rescale(rx_w, claimed)[:, None]
+                est = agg.masked_median(per_user, mask)
+            else:
+                est = agg.masked_trimmed_mean(per_user, mask, cfg.agg_trim)
+        else:
+            est = cluster_ota(k2, flat, topo, P_t, cfg.ota)  # [C, 2N]
+        if rows != C:
+            est = jnp.pad(est, ((0, rows - C), (0, 0)))
         return est
 
-    def round_fn(state, key, P_t, P_is_t):
+    parts = Engine(train=None, send=send, cluster=cluster_fold,
+                   edge_power=agg.symbol_power, flat=lambda flat: flat)
+
+    def body(eng: Engine, state, key, P_t, P_is_t):
         if trace_counter is not None:
             trace_counter[0] += 1  # python side effect: runs at trace time
         theta = state["theta"]
@@ -328,15 +368,16 @@ def make_round_fn(loss_fn: Callable, opt: Optimizer, topo: Topology,
             mult = claimed * tx_base
         else:
             claimed = mult = None
+        theta_IS = jax.tree.map(
+            lambda x: jnp.broadcast_to(x, (rows,) + x.shape), theta)
 
         if cfg.mode == "conventional":
-            theta_IS = jax.tree.map(
-                lambda x: jnp.broadcast_to(x, (C,) + x.shape), theta)
             k1, k2 = jax.random.split(key)
-            flat, opt_state = users_train(theta_IS, state["opt"], k1, step)
+            tx, opt_state = eng.train(theta_IS, state["opt"], k1, step,
+                                      mult)
             with jax.named_scope("whfl.ps_hop"):
-                if partial:
-                    flat = agg.cotaf_precode(flat, mult)
+                sent = eng.send(tx, mult)
+                flat = eng.flat(sent)
                 est = conventional_ota(k2, maybe_poison(flat, step), topo,
                                        P_t, cfg.ota)
                 if partial:
@@ -346,7 +387,7 @@ def make_round_fn(loss_fn: Callable, opt: Optimizer, topo: Topology,
                 if guard_on:
                     est, g_trip = guard_estimate(est, cfg.guard)
                 theta = apply_updates(theta, agg.unflatten(spec, est))
-                p_edge = agg.symbol_power(flat, P_t)
+                p_edge = eng.edge_power(sent, P_t)
                 out = {**state, "theta": theta, "opt": opt_state,
                        "t": step + 1,
                        "power_edge": state["power_edge"] + p_edge,
@@ -363,19 +404,20 @@ def make_round_fn(loss_fn: Callable, opt: Optimizer, topo: Topology,
             return out
 
         # --- W-HFL ---
-        theta_IS = jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (C,) + x.shape), theta)
-
         def cluster_iter(carry, k):
             th_IS, opt_state, p_acc = carry[:3]
             g_acc = carry[3] if guard_on else None
             k1, k2 = jax.random.split(k)
-            flat, opt_state = users_train(th_IS, opt_state, k1, step)
+            tx, opt_state = eng.train(th_IS, opt_state, k1, step, mult)
             with jax.named_scope("whfl.cluster_hop"):
-                if partial:
-                    flat = agg.cotaf_precode(flat, mult)
-                est = cluster_fold(k2, maybe_poison(flat, step), claimed,
-                                   P_t)                     # [C, 2N]
+                sent = eng.send(tx, mult)
+                est = eng.cluster(k2, sent, step, claimed, P_t)
+                if partial and not robust:
+                    resc = agg.attendance_rescale(rx_w, claimed)   # [C]
+                    if rows != C:   # inactive rows stay exactly zero
+                        resc = jnp.pad(resc, (0, rows - C),
+                                       constant_values=1.0)
+                    est = est * resc[:, None]
             with jax.named_scope("whfl.update"):
                 if guard_on:
                     est, g_trip = guard_estimate(est, cfg.guard)
@@ -384,12 +426,13 @@ def make_round_fn(loss_fn: Callable, opt: Optimizer, topo: Topology,
                     lambda th, e: apply_updates(th, agg.unflatten(spec, e))
                 )(th_IS, est)
                 out = (th_IS, opt_state,
-                       p_acc + agg.symbol_power(flat, P_t))
+                       p_acc + eng.edge_power(sent, P_t))
                 if guard_on:
                     out += (g_acc,)
             if tele_on:
                 # the last cluster iteration's block survives
-                out += (cluster_telemetry(flat, est, claimed, topo, P_t),)
+                out += (cluster_telemetry(eng.flat(sent), real_rows(est),
+                                          claimed, topo, P_t),)
             return out, None
 
         keys = jax.random.split(key, cfg.I + 1)
@@ -403,11 +446,12 @@ def make_round_fn(loss_fn: Callable, opt: Optimizer, topo: Topology,
         g_edge = carry[3] if guard_on else None
         tele_blk = carry[3 + int(guard_on)] if tele_on else None
 
+        # only the real clusters transmit to the PS
         with jax.named_scope("whfl.ps_hop"):
             is_deltas = jax.vmap(
                 lambda th: agg.flatten(
                     spec, jax.tree.map(lambda a, b: a - b, th, theta)))(
-                        theta_IS)
+                        jax.tree.map(real_rows, theta_IS))
             est = global_ota(keys[-1], is_deltas, topo, P_is_t, cfg.ota)
         with jax.named_scope("whfl.update"):
             if guard_on:
@@ -426,6 +470,47 @@ def make_round_fn(loss_fn: Callable, opt: Optimizer, topo: Topology,
             out["telemetry"] = {**tele_blk,
                                 **is_telemetry(is_deltas, topo, P_is_t)}
         return out
+
+    return body, parts
+
+
+def make_round_fn(loss_fn: Callable, opt: Optimizer, topo: Topology,
+                  cfg: WHFLConfig, spec: agg.FlatSpec, X, Y,
+                  trace_counter: Optional[list] = None) -> Callable:
+    """Build the single engine's pure per-round function
+    ``round_fn(state, key, P_t, P_is_t) -> state``: the round body of
+    `make_round_body` with its real-block `Engine` parts, and users
+    trained in one vmap over ``(cluster, user)`` on this device.
+
+    Everything static (data shards, topology geometry, config, flat
+    spec) is closed over; the returned function touches no mutable
+    state, so it can be wrapped in `jax.jit` once and additionally
+    lifted with `jax.vmap` over a leading seed axis of ``(state, key)``
+    — S seeds share one trace/compile.  `trace_counter`: see
+    `make_round_body`.
+    """
+    C, M = topo.C, topo.M
+    X = jnp.asarray(X)
+    Y = jnp.asarray(Y)
+    local_train = make_local_train(loss_fn, opt, cfg)
+    body, parts = make_round_body(topo, cfg, spec,
+                                  trace_counter=trace_counter)
+
+    @jax.named_scope("whfl.train")
+    def users_train(theta_IS, opt_state, key, step, mult):
+        """theta_IS: [C]-stacked cluster models -> flat deltas [C,M,2N]."""
+        keys = jax.random.split(key, C * M).reshape(C, M, 2)
+        train_u = lambda th, st, x, y, k: local_train(th, st, x, y, k, step)
+        train_c = jax.vmap(train_u, in_axes=(None, 0, 0, 0, 0))
+        deltas, opt_state = jax.vmap(train_c)(theta_IS, opt_state, X, Y,
+                                              keys)
+        flat = jax.vmap(jax.vmap(lambda d: agg.flatten(spec, d)))(deltas)
+        return flat, opt_state
+
+    engine = parts._replace(train=users_train)
+
+    def round_fn(state, key, P_t, P_is_t):
+        return body(engine, state, key, P_t, P_is_t)
 
     return round_fn
 

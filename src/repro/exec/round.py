@@ -1,7 +1,12 @@
 """The W-HFL round under `shard_map`: one (cluster, user) mesh, two
 work splits, zero drift from the mesh shape.
 
-Phase 1 — local training.  The per-user program is
+The round itself is `repro.core.whfl.make_round_body`, the one body the
+single engine runs too: its gates, hops, updates, counters, telemetry
+and scopes.  This module supplies the `repro.core.whfl.Engine` parts
+that differ on a mesh, the pad plan, and the `shard_map` around them.
+
+Local training.  The per-user program is
 `repro.core.whfl.make_local_train` (the same unit the single-device
 engine vmaps); here every mesh shard `jax.lax.map`s it over its local
 ``(C_loc, M_loc)`` block of users.  `lax.map` runs the *identical*
@@ -10,45 +15,44 @@ bitwise the same no matter how many devices the users are spread over
 (the established `batch="map"` property of the sweep engine, applied
 to the user axis).
 
-Phase 2 — the OTA hops.  The cluster hop with the ``fused`` backend is
-the scaling path: every receiving IS hears every user, so the transmit
-symbols are redistributed (all_to_all over symbols, all_gather over
-clusters) and each shard runs the fused matched-filter combine for its
-``C_loc`` rx stations x ``N_loc`` symbols, passing its tile origin as
-the kernel's counter bases (`rx_base`/`n_base`).  The counter PRNG
-keys on global (rx, u, k, n) indices only, so every shard draws
-exactly the channels the full-range call would have drawn — the hop is
-bitwise invariant to mesh shape, and there is *no* cross-device
-reduction (the u/k folds happen entirely in-kernel, in a mesh-
-independent block order).  All other backends (reference /
-equivalent / ideal), the conventional baseline and the small IS -> PS
-hop gather the (much smaller) inputs and compute replicated — the same
-full-shape program on every device, which is trivially mesh-invariant.
-
-Power accounting sums per-user energies locally, gathers the tiny
-``[C, M]`` grid and folds it in a fixed order, again mesh-invariant.
+The hops.  The cluster hop with the ``fused`` backend is the scaling
+path: every receiving IS hears every user, so the transmit symbols are
+redistributed (all_to_all over symbols, all_gather over clusters) and
+each shard runs the fused matched-filter combine for its ``C_loc`` rx
+stations x ``N_loc`` symbols, passing its tile origin as the kernel's
+counter bases (`rx_base`/`n_base`).  The counter PRNG keys on global
+(rx, u, k, n) indices only, so every shard draws exactly the channels
+the full-range call would have drawn — the hop is bitwise invariant to
+mesh shape, and there is *no* cross-device reduction (the u/k folds
+happen entirely in-kernel, in a mesh-independent block order).  On that
+path users precode as they train and sum their own energies, which the
+power fold gathers as a tiny ``[C, M]`` grid.  Every other hop gathers
+the real ``[C, M, 2N]`` block and runs the single engine's own parts
+on it, replicated: trivially mesh-invariant, and the single engine's
+ops on the same values.
 
 Uneven meshes — any mesh runs any scenario.  When the mesh does not
 divide (C, M), the workload is padded up to the mesh with *inactive*
 users and clusters (`repro.exec.mesh.pad_plan_for`): padded users
 train on zero dummy shards (``lax.map`` skips nothing — the per-slice
 program stays identical, so real users' deltas are untouched) but
-their transmissions never exist — every OTA hop, and the power
-accounting, slices the gathered grid back to the real ``[:C, :M]``
-block before computing, and the fused cluster hop drops inactive rows
-so real users keep their *unpadded* global counter indices (their h/z
-draws are exactly the single-engine draws; inactive rx stations get
-zero-amplitude geometry rows and draw only at padded rx counters).
-The result extends the mesh-invariance theorem to all meshes: a padded
-sharded run is bitwise invariant to the mesh shape for every scenario,
-and bitwise identical to the unpadded single-engine ``batch="map"``
-run — final params, optimizer state, metrics and per-round power — for
-the paper's scenarios, on both round drivers
-(tests/test_uneven_mesh.py pins both).  Model state is bitwise
-cross-engine everywhere; the one known exception is the scalar power
-metrics on some odd fused-backend shapes, where XLA:CPU layout
-assignment rounds the energy fold 1 ULP apart between the two
-programs (bounded by the same tests).
+their transmissions never exist — every hop, and the power accounting,
+reads only the real ``[:C, :M]`` block, and the fused cluster hop
+drops inactive rows so real users keep their *unpadded* global counter
+indices (their h/z draws are exactly the single-engine draws; inactive
+rx stations get zero-amplitude geometry rows and draw only at padded
+rx counters).  The round body carries ``theta_IS`` in the padded Cp
+rows and drops the inactive ones before the PS hop.  The result
+extends the mesh-invariance theorem to all meshes: a padded sharded
+run is bitwise invariant to the mesh shape for every scenario, and
+bitwise identical to the unpadded single-engine ``batch="map"`` run —
+final params, optimizer state, metrics and per-round power — for the
+paper's scenarios, on both round drivers (tests/test_uneven_mesh.py
+and tests/test_ft.py pin both).  Model state is bitwise cross-engine
+everywhere; the one known exception is the scalar power metrics on
+some odd fused-backend shapes, where XLA:CPU layout assignment rounds
+the energy fold 1 ULP apart between the two programs (bounded by the
+same tests).
 
 Everything runs *fully manual* (both mesh axes): every collective is
 explicit, so the program is the same on every backend.
@@ -64,46 +68,38 @@ from jax.sharding import PartitionSpec as P
 import numpy as np
 
 from repro.core import aggregation as agg
-from repro.core.channel import (_cluster_geometry, _seed_words, cluster_ota,
-                                conventional_ota, global_ota,
-                                orthogonal_cluster_ota, resolve_backend)
+from repro.core.channel import _cluster_geometry, _seed_words, resolve_backend
 from repro.core.topology import Topology
-from repro.core.whfl import (WHFLConfig, make_local_train,
-                             validate_participation)
+from repro.core.whfl import (Engine, WHFLConfig, make_chunk_fn,
+                             make_local_train, make_round_body)
 from repro.exec.mesh import pad_plan_for
-from repro.ft.guard import guard_estimate, validate_guard
 from repro.kernels import fused_mac, interpret_mode
-from repro.obs.telemetry import (cluster_telemetry, edge_telemetry_init,
-                                 is_telemetry, is_telemetry_zero)
 # the executor's symbol padding must agree with the kernel's rounding
 from repro.kernels.fused_mac import (_round_up, canonical_block_u,
                                      fused_mac_partials, fused_noise,
                                      fused_partials_reduce)
-from repro.optim import Optimizer, apply_updates
+from repro.optim import Optimizer
 from repro.sharding import shard_map
 
 
 COMBINES = ("gathered", "u_sharded")
 
 
-def _build_round_parts(loss_fn: Callable, opt: Optimizer, topo: Topology,
-                       cfg: WHFLConfig, spec: agg.FlatSpec, X, Y, mesh,
-                       trace_counter: Optional[list] = None,
-                       combine: str = "gathered"):
-    """Construct the per-shard round body shared by both sharded entry
+def _build_round(loss_fn: Callable, opt: Optimizer, topo: Topology,
+                 cfg: WHFLConfig, spec: agg.FlatSpec, X, Y, mesh,
+                 trace_counter: Optional[list] = None,
+                 combine: str = "gathered"):
+    """Construct the per-shard round shared by both sharded entry
     points: `make_sharded_round_fn` (one shard_map per round) and
-    `make_sharded_chunk_fn` (a lax.scan of the same body *inside* one
-    shard_map per eval window).  Returns ``(_round, state_spec, X, Y)``
-    where `_round(state, key, P_t, P_is_t, X_loc, Y_loc)` is valid only
-    inside a shard_map over ``("cluster", "user")``.
+    `make_sharded_chunk_fn` (a lax.scan of the same round *inside* one
+    shard_map per eval window).  Returns ``(_round, X, Y)`` where
+    `_round(state, key, P_t, P_is_t, X_loc, Y_loc)` is valid only
+    inside a shard_map over ``("cluster", "user")``: the round body of
+    `repro.core.whfl.make_round_body` over this shard's `Engine` parts.
 
-    A mesh that does not divide (C, M) is handled by padding the
-    workload with inactive users/clusters (`pad_plan_for`): the state's
-    ``opt`` axes, the data shards and the per-shard layout all use the
-    padded (Cp, Mp) grid, while every hop and the power accounting
-    compute on the real ``[:C, :M]`` block only — see module docstring.
-    Callers building states directly must size the opt axes to
-    ``(plan.Cp, plan.Mp)`` (the sweep runners do this automatically).
+    On a mesh that does not divide (C, M) the state's ``opt`` axes, the
+    data shards and the per-shard layout use the padded (Cp, Mp) grid
+    of `pad_plan_for` (see module docstring).
 
     ``combine`` selects the fused cluster hop's distribution strategy:
     ``"gathered"`` (default) all-gathers the `[U, N_loc]` symbol block
@@ -123,64 +119,13 @@ def _build_round_parts(loss_fn: Callable, opt: Optimizer, topo: Topology,
     Cp, Mp = plan.Cp, plan.Mp
     mc, mu = mesh.devices.shape
     C_loc, M_loc = Cp // mc, Mp // mu
-    two_n = spec.two_n
-    N = two_n // 2
+    N = spec.two_n // 2
     Np = _round_up(N, mu)       # symbol axis padded to split over 'user'
     N_loc = Np // mu
     local_train = make_local_train(loss_fn, opt, cfg)
     interpret = interpret_mode()
-
-    # Participation / robustness gates mirror the single engine's
-    # Python-level branches (repro.core.whfl.make_round_fn): a full
-    # schedule with the mean fold builds the identical pre-participation
-    # program, and every participation op below composes with the pad
-    # plan (a sampled-out user is a pad slot drawn per round: tx
-    # multiplier 0, so its transmission never exists on any mesh).
-    validate_participation(cfg)
-    schedule = cfg.participation
-    partial = not schedule.is_full
-    robust = cfg.cluster_agg != "mean"
-    # telemetry mirrors the single engine's Python-level gate: off
-    # inserts nothing; on computes the identical fence-isolated
-    # diagnostics from the *gathered* (real, unpadded) values, so the
-    # block is replicated on every shard and mesh-invariant
-    tele_on = cfg.telemetry
-    # fault-tolerance gates (repro.ft), Python-level like the single
-    # engine's: guard "off" / poison None insert nothing.  The guard
-    # runs on the REPLICATED [Cp, 2N] estimate — padded rows are
-    # exactly zero (finite), so the trip bit, the zeroing selections
-    # and hence the guarded real rows are identical on every mesh and
-    # to the single engine's [C, 2N] guard.
-    validate_guard(cfg.guard)
-    guard_on = cfg.guard != "off"
-    poison = cfg.poison
-    if poison is not None:
-        if poison.c >= C or poison.m >= M:
-            raise ValueError(
-                f"poison targets user ({poison.c}, {poison.m}) outside "
-                f"the ({C}, {M}) grid")
-        _pmask = np.zeros((C, M), bool)
-        _pmask[poison.c, poison.m] = True
-        _pmask_p = jnp.asarray(plan.pad_users(_pmask))     # [Cp, Mp]
-
-    def maybe_poison_loc(flat_loc, step, ci, ui):
-        """Poison the fold input of this shard's block iff it owns the
-        targeted user — the same per-coordinate `flat + where(...)`
-        the single engine applies, restricted to the local tile, so
-        the poisoned symbols are bitwise cross-engine.  Python-level
-        no-op when poison is None."""
-        if poison is None:
-            return flat_loc
-        mask_loc = jax.lax.dynamic_slice(
-            _pmask_p, (ci * C_loc, ui * M_loc), (C_loc, M_loc))
-        hit = jnp.logical_and(step == poison.t, mask_loc)
-        return flat_loc + jnp.where(hit, poison.value, 0.0)[..., None]
-
-    tx_base = jnp.asarray(schedule.tx_base(C, M)) if partial else None
-    rx_w = (np.ones((C, M), np.float32) if cfg.ota.mode == "ideal"
-            else np.asarray(topo.beta_own, np.float32))
-    rx_w_conv = (np.ones((C, M), np.float32) if cfg.ota.mode == "ideal"
-                 else np.asarray(topo.beta_mu_ps, np.float32))
+    body, parts = make_round_body(topo, cfg, spec, rows=Cp,
+                                  trace_counter=trace_counter)
 
     backend = ("" if cfg.ota.mode == "ideal" else resolve_backend(cfg.ota))
     fused_cluster_hop = (cfg.mode != "conventional" and backend == "fused")
@@ -213,6 +158,11 @@ def _build_round_parts(loss_fn: Callable, opt: Optimizer, topo: Topology,
             bk_c = min(8, topo.K)
             Kp_c = _round_up(topo.K, bk_c)
             G_real = C * M // bu_c
+        if cfg.poison is not None:
+            # the fault plan's user in the padded grid: real users keep
+            # their (c, m), so each shard poisons its own tile of it
+            pmask = np.zeros((Cp, Mp), bool)
+            pmask[cfg.poison.c, cfg.poison.m] = True
 
     X = plan.pad_users(jnp.asarray(X))   # inactive users: zero shards
     Y = plan.pad_users(jnp.asarray(Y))
@@ -227,15 +177,15 @@ def _build_round_parts(loss_fn: Callable, opt: Optimizer, topo: Topology,
         x = jax.lax.all_gather(x, "cluster", axis=0, tiled=True)
         return plan.unpad_users(x)
 
-    def _slice_c(tree, ci):
-        """Replicated [Cp, ...] pytree -> this shard's [C_loc, ...] rows."""
-        return jax.tree.map(
-            lambda x: jax.lax.dynamic_slice_in_dim(x, ci * C_loc, C_loc, 0),
-            tree)
+    def _tile(x, ci, ui):
+        """Padded [Cp, Mp, ...] -> this shard's [C_loc, M_loc, ...] block."""
+        return jax.lax.dynamic_slice(
+            x, (ci * C_loc, ui * M_loc) + (0,) * (x.ndim - 2),
+            (C_loc, M_loc) + x.shape[2:])
 
     @jax.named_scope("whfl.train")
     def users_train(theta_IS, opt_loc, key, step, X_loc, Y_loc, ci, ui,
-                    mult_p=None):
+                    mult=None):
         """Local training of this shard's users.
 
         theta_IS: replicated [Cp]-stacked cluster models; opt/X/Y: the
@@ -248,7 +198,7 @@ def _build_round_parts(loss_fn: Callable, opt: Optimizer, topo: Topology,
         bitwise the single-engine delta; inactive deltas are computed
         but never transmitted).
 
-        `mult_p` (padded [Cp, Mp], participation runs only): the round's
+        `mult` (real [C, M], fused participation runs only): the round's
         COTAF transmit multipliers.  Each user's flat delta is precoded
         *before* its energy is computed inside the per-user map, the
         same elementwise multiply the single engine batches
@@ -257,51 +207,29 @@ def _build_round_parts(loss_fn: Callable, opt: Optimizer, topo: Topology,
         sampled-out user is exactly a pad slot).
         """
         keys = jax.random.split(key, C * M).reshape(C, M, 2)
-        keys = plan.pad_users(keys)                     # [Cp, Mp, 2]
-        keys_loc = jax.lax.dynamic_slice(
-            keys, (ci * C_loc, ui * M_loc, 0), (C_loc, M_loc, 2))
-        theta_loc = _slice_c(theta_IS, ci)
-        if partial:
-            mult_loc = jax.lax.dynamic_slice(
-                mult_p, (ci * C_loc, ui * M_loc), (C_loc, M_loc))
+        theta_loc = jax.tree.map(
+            lambda x: jax.lax.dynamic_slice_in_dim(x, ci * C_loc, C_loc, 0),
+            theta_IS)
+        xs = (theta_loc, opt_loc, X_loc, Y_loc,
+              _tile(plan.pad_users(keys), ci, ui))
+        if mult is not None:
+            xs += (_tile(plan.pad_users(mult), ci, ui),)
 
         def one_cluster(args):
-            if partial:
-                th_c, opt_c, x_c, y_c, k_c, m_c = args
-            else:
-                th_c, opt_c, x_c, y_c, k_c = args
+            th_c = args[0]
 
             def one_user(a):
-                if partial:
-                    st, x, y, k, m = a
-                else:
-                    st, x, y, k = a
-                delta, st = local_train(th_c, st, x, y, k, step)
+                delta, st = local_train(th_c, a[0], a[1], a[2], a[3], step)
                 flat = agg.flatten(spec, delta)
-                if partial:
-                    flat = flat * m
+                if mult is not None:
+                    flat = flat * a[4]
                 with jax.named_scope("whfl.update"):   # power accounting
                     energy = agg.user_energy(flat)
                 return flat, st, energy
 
-            xs = ((opt_c, x_c, y_c, k_c, m_c) if partial
-                  else (opt_c, x_c, y_c, k_c))
-            return jax.lax.map(one_user, xs)
+            return jax.lax.map(one_user, args[1:])
 
-        xs = ((theta_loc, opt_loc, X_loc, Y_loc, keys_loc, mult_loc)
-              if partial else (theta_loc, opt_loc, X_loc, Y_loc, keys_loc))
-        flat, opt_loc, pw = jax.lax.map(one_cluster, xs)
-        return flat, opt_loc, pw
-
-    def edge_power(pw_loc, P_t):
-        """Mesh-invariant `agg.symbol_power`: per-user energies are
-        gathered to the tiny real [C, M] grid (inactive users sliced
-        off) and folded through the same fenced subgraph the single
-        engine uses (`agg.symbol_power_from_energy`), so the scalar is
-        bitwise identical across meshes (and across engines for the
-        paper scenarios — see module docstring)."""
-        pw = _gather_cm(pw_loc)
-        return agg.symbol_power_from_energy(pw, P_t, N)
+        return jax.lax.map(one_cluster, xs)
 
     def fused_cluster_estimate(key, flat_loc, P_t, ci, ui):
         """Sharded fused cluster hop: rx stations over 'cluster',
@@ -412,179 +340,64 @@ def _build_round_parts(loss_fn: Callable, opt: Optimizer, topo: Topology,
         est_im = collect(y_im / topo.K / scale)
         return jnp.concatenate([est_re, est_im], axis=-1)   # [Cp, 2N]
 
-    def cluster_estimate(key, flat_loc, P_t, ci, ui, claimed=None):
-        """Replicated [Cp, 2N] cluster estimate; real rows == the
-        single-engine cluster fold, inactive rows zero (padded with a
-        1.0 rescale, so they stay exactly zero under participation).
+    def fused_engine(X_loc, Y_loc, ci, ui):
+        """`Engine` parts of the fused cluster hop: users precode and
+        sum their energies as they train (``tx = sent = (flat_loc,
+        pw_loc)``), the shard poisons its own tile, and the hop returns
+        the replicated [Cp, 2N] estimate, inactive rows zero."""
+        def train(theta_IS, opt_loc, key, step, mult):
+            flat, opt_loc, pw = users_train(theta_IS, opt_loc, key, step,
+                                            X_loc, Y_loc, ci, ui, mult)
+            return (flat, pw), opt_loc
 
-        Mirrors `repro.core.whfl.make_round_fn`'s `cluster_fold`: OTA
-        superposition mean (+ COTAF attendance rescale under partial
-        participation) or a robust masked fold over orthogonalized
-        per-user receptions (small backends only, computed replicated
-        on the gathered real block — the literal single-engine
-        program, hence bitwise cross-engine/mesh)."""
-        if fused_cluster_hop:
-            est = (fused_cluster_estimate_u_sharded(key, flat_loc, P_t,
-                                                    ci, ui)
-                   if combine == "u_sharded" else
-                   fused_cluster_estimate(key, flat_loc, P_t, ci, ui))
-            if partial:
-                resc = agg.attendance_rescale(rx_w, claimed)    # [C]
-                est = est * plan.pad_rx(resc, fill=1.0)[:, None]
-            return est
-        flat = _gather_cm(flat_loc)
-        if robust:
-            mask = (claimed if partial
-                    else jnp.ones((C, M), jnp.float32))
-            per_user = orthogonal_cluster_ota(key, flat, topo, P_t,
-                                              cfg.ota)
-            if cfg.cluster_agg == "median":
-                return plan.pad_rx(agg.masked_median(per_user, mask))
-            return plan.pad_rx(
-                agg.masked_trimmed_mean(per_user, mask, cfg.agg_trim))
-        # small/closed-form backends: gather the real block and compute
-        # replicated — the literal single-engine hop on identical input
-        # (inactive clusters receive a zero-padded estimate row)
-        est = cluster_ota(key, flat, topo, P_t, cfg.ota)
-        if partial:
-            est = est * agg.attendance_rescale(rx_w, claimed)[:, None]
-        return plan.pad_rx(est)
+        def cluster(key, sent, step, claimed, P_t):
+            flat_loc = sent[0]
+            if cfg.poison is not None:
+                hit = jnp.logical_and(step == cfg.poison.t,
+                                      _tile(jnp.asarray(pmask), ci, ui))
+                flat_loc = flat_loc + jnp.where(
+                    hit, cfg.poison.value, 0.0)[..., None]
+            if combine == "u_sharded":
+                return fused_cluster_estimate_u_sharded(key, flat_loc, P_t,
+                                                        ci, ui)
+            return fused_cluster_estimate(key, flat_loc, P_t, ci, ui)
 
-    # -- the round body ------------------------------------------------------
+        def edge_power(sent, P_t):   # mesh-invariant `agg.symbol_power`
+            return agg.symbol_power_from_energy(_gather_cm(sent[1]), P_t, N)
+
+        return Engine(train=train, send=lambda tx, mult: tx,
+                      cluster=cluster, edge_power=edge_power,
+                      flat=lambda sent: _gather_cm(sent[0]))
+
+    def gathered_engine(X_loc, Y_loc, ci, ui):
+        """`Engine` parts of every other hop: the real [C, M, 2N] block,
+        gathered as it is sent, then the single engine's own parts."""
+        def train(theta_IS, opt_loc, key, step, mult):
+            flat, opt_loc, _ = users_train(theta_IS, opt_loc, key, step,
+                                           X_loc, Y_loc, ci, ui)
+            return flat, opt_loc
+
+        return parts._replace(
+            train=train,
+            send=lambda tx, mult: parts.send(_gather_cm(tx), mult))
+
+    engine = fused_engine if fused_cluster_hop else gathered_engine
 
     def _round(state, key, P_t, P_is_t, X_loc, Y_loc):
-        if trace_counter is not None:
-            trace_counter[0] += 1  # python side effect: runs at trace time
         ci = jax.lax.axis_index("cluster")
         ui = jax.lax.axis_index("user")
-        theta = state["theta"]
-        step = state["t"]
-        if partial:
-            # replicated on every shard: the mask is a pure function of
-            # (schedule, step) through the counter PRNG, so all shards
-            # (and the single engine) draw the identical [C, M] grid
-            claimed = schedule.present(step, C, M)
-            mult_p = plan.pad_users(claimed * tx_base)      # [Cp, Mp]
-        else:
-            claimed = mult_p = None
-        theta_IS = jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (Cp,) + x.shape), theta)
+        return body(engine(X_loc, Y_loc, ci, ui), state, key, P_t, P_is_t)
 
-        if cfg.mode == "conventional":
-            k1, k2 = jax.random.split(key)
-            flat_loc, opt_state, pw = users_train(
-                theta_IS, state["opt"], k1, step, X_loc, Y_loc, ci, ui,
-                mult_p)
-            with jax.named_scope("whfl.ps_hop"):
-                flat = _gather_cm(flat_loc)
-                est = conventional_ota(
-                    k2, _gather_cm(maybe_poison_loc(flat_loc, step, ci, ui))
-                    if poison is not None else flat, topo, P_t, cfg.ota)
-                if partial:
-                    est = est * agg.attendance_rescale(
-                        rx_w_conv.reshape(-1), claimed.reshape(-1))
-            with jax.named_scope("whfl.update"):
-                if guard_on:
-                    est, g_trip = guard_estimate(est, cfg.guard)
-                theta = apply_updates(theta, agg.unflatten(spec, est))
-                out = {**state, "theta": theta, "opt": opt_state,
-                       "t": step + 1,
-                       "power_edge": (state["power_edge"]
-                                      + edge_power(pw, P_t)),
-                       "n_edge_tx": state["n_edge_tx"] + 1.0,
-                       "power_is": state["power_is"],
-                       "n_is_tx": state["n_is_tx"]}
-                if guard_on:
-                    out["guard_trips"] = state["guard_trips"] + g_trip
-            if tele_on:
-                out["telemetry"] = {
-                    **cluster_telemetry(flat, est, claimed, topo, P_t,
-                                        mode="conventional"),
-                    **is_telemetry_zero()}
-            return out
+    return _round, X, Y
 
-        # --- W-HFL ---
-        def cluster_iter(carry, k):
-            th_IS, opt_state, p_acc = carry[:3]
-            g_acc = carry[3] if guard_on else None
-            k1, k2 = jax.random.split(k)
-            flat_loc, opt_state, pw = users_train(
-                th_IS, opt_state, k1, step, X_loc, Y_loc, ci, ui, mult_p)
-            with jax.named_scope("whfl.cluster_hop"):
-                est = cluster_estimate(
-                    k2, maybe_poison_loc(flat_loc, step, ci, ui), P_t, ci,
-                    ui, claimed)                             # [Cp, 2N]
-            with jax.named_scope("whfl.update"):
-                if guard_on:
-                    est, g_trip = guard_estimate(est, cfg.guard)
-                    g_acc = g_acc + g_trip
-                th_IS = jax.vmap(
-                    lambda th, e: apply_updates(th, agg.unflatten(spec, e))
-                )(th_IS, est)
-                out = (th_IS, opt_state, p_acc + edge_power(pw, P_t))
-                if guard_on:
-                    out += (g_acc,)
-            if tele_on:
-                # the last cluster iteration's block survives
-                # gathered real [C, M, 2N] deltas + real estimate rows:
-                # the literal single-engine telemetry inputs, computed
-                # replicated (opt-in cost; the off-path has no gather)
-                est_r = est if Cp == C else est[:C]
-                out += (cluster_telemetry(_gather_cm(flat_loc), est_r,
-                                          claimed, topo, P_t),)
-            return out, None
 
-        keys = jax.random.split(key, cfg.I + 1)
-        carry0 = (theta_IS, state["opt"], jnp.zeros(()))
-        if guard_on:
-            carry0 += (jnp.zeros((), jnp.int32),)
-        if tele_on:
-            carry0 += (edge_telemetry_init(C),)
-        carry, _ = jax.lax.scan(cluster_iter, carry0, keys[: cfg.I])
-        theta_IS, opt_state, p_edge = carry[:3]
-        g_edge = carry[3] if guard_on else None
-        tele_blk = carry[3 + int(guard_on)] if tele_on else None
+_USERS = P("cluster", "user")   # per-user data and optimizer state
 
-        # only the real clusters transmit to the PS
-        with jax.named_scope("whfl.ps_hop"):
-            theta_IS_act = (theta_IS if Cp == C else
-                            jax.tree.map(lambda x: x[:C], theta_IS))
-            is_deltas = jax.vmap(
-                lambda th: agg.flatten(
-                    spec, jax.tree.map(lambda a, b: a - b, th, theta)))(
-                        theta_IS_act)
-            est = global_ota(keys[-1], is_deltas, topo, P_is_t, cfg.ota)
-        with jax.named_scope("whfl.update"):
-            if guard_on:
-                est, g_is = guard_estimate(est, cfg.guard)
-            theta = apply_updates(theta, agg.unflatten(spec, est))
-            p_is = agg.symbol_power(is_deltas, P_is_t)
-            out = {**state, "theta": theta, "opt": opt_state,
-                   "t": step + 1,
-                   "power_edge": state["power_edge"] + p_edge,
-                   "n_edge_tx": state["n_edge_tx"] + float(cfg.I),
-                   "power_is": state["power_is"] + p_is,
-                   "n_is_tx": state["n_is_tx"] + 1.0}
-            if guard_on:
-                out["guard_trips"] = state["guard_trips"] + g_edge + g_is
-        if tele_on:
-            out["telemetry"] = {**tele_blk,
-                                **is_telemetry(is_deltas, topo, P_is_t)}
-        return out
 
-    state_spec = {
-        "theta": P(), "opt": P("cluster", "user"), "t": P(),
-        "power_edge": P(), "power_is": P(), "n_edge_tx": P(),
-        "n_is_tx": P(),
-    }
-    if tele_on:
-        # the whole diagnostics block is computed from gathered values,
-        # hence replicated (the tree-prefix P() covers every leaf)
-        state_spec["telemetry"] = P()
-    if guard_on:
-        # computed from the replicated estimates, hence replicated
-        state_spec["guard_trips"] = P()
-    return _round, state_spec, X, Y
+def _state_spec(state) -> dict:
+    """Every state leaf is replicated, but the per-user ``opt`` state,
+    which is sharded over the (cluster, user) mesh."""
+    return {k: _USERS if k == "opt" else P() for k in state}
 
 
 def make_sharded_round_fn(loss_fn: Callable, opt: Optimizer, topo: Topology,
@@ -593,6 +406,14 @@ def make_sharded_round_fn(loss_fn: Callable, opt: Optimizer, topo: Topology,
                           combine: str = "gathered") -> Callable:
     """Build ``round_fn(state, key, P_t, P_is_t) -> state`` running one
     W-HFL round sharded over `mesh` (axes ``("cluster", "user")``).
+
+    The round is `repro.core.whfl.make_round_body`'s, the single
+    engine's too.  This engine supplies its `Engine` parts: users
+    trained by a `lax.map` over each shard's block; for every hop but
+    the fused cluster hop, their deltas gathered to the real block as
+    they are sent and the single engine's parts from there; for the
+    fused cluster hop, users that precode and sum their energies as
+    they train, the sharded fused estimate and the energies' fold.
 
     Same contract as `repro.core.whfl.make_round_fn` — pure, jit-able,
     seed-batchable — plus the mesh-invariance guarantee: for a fixed
@@ -605,16 +426,15 @@ def make_sharded_round_fn(loss_fn: Callable, opt: Optimizer, topo: Topology,
     runners do this automatically).  Pinned by
     `tests/test_exec_sharded.py` and `tests/test_uneven_mesh.py`.
     """
-    _round, state_spec, X, Y = _build_round_parts(
-        loss_fn, opt, topo, cfg, spec, X, Y, mesh,
-        trace_counter=trace_counter, combine=combine)
-    sharded = shard_map(
-        _round, mesh=mesh,
-        in_specs=(state_spec, P(), P(), P(),
-                  P("cluster", "user"), P("cluster", "user")),
-        out_specs=state_spec, check_vma=False)
+    _round, X, Y = _build_round(loss_fn, opt, topo, cfg, spec, X, Y, mesh,
+                                trace_counter=trace_counter,
+                                combine=combine)
 
     def round_fn(state, key, P_t, P_is_t):
+        spec = _state_spec(state)
+        sharded = shard_map(_round, mesh=mesh,
+                            in_specs=(spec, P(), P(), P(), _USERS, _USERS),
+                            out_specs=spec, check_vma=False)
         return sharded(state, key, jnp.float32(P_t), jnp.float32(P_is_t),
                        X, Y)
 
@@ -628,40 +448,34 @@ def make_sharded_chunk_fn(loss_fn: Callable, opt: Optimizer, topo: Topology,
                           combine: str = "gathered") -> Callable:
     """Build ``chunk_fn(state, key, P_win, P_is_win) -> (state, key,
     metrics)`` running ``len(P_win)`` sharded W-HFL rounds in a single
-    `lax.scan` *inside* one shard_map — the sharded-engine counterpart
-    of `repro.core.whfl.make_chunk_fn`, so the host stops paying a
+    `lax.scan` *inside* one shard_map, so the host stops paying a
     shard_map re-entry + dispatch barrier per round.
 
-    The scan body is exactly the `_round` body the per-round entry
-    point runs (same key chain as the stepwise driver: ``key, sub =
-    split(key)`` per round — threefry is integer-exact and replicated
-    identically on every shard), so chunked sharded sweeps are bitwise
-    equal to stepwise sharded sweeps AND retain the engine's bitwise
-    mesh-invariance.  `eval_fn(state)` (optional) is folded into the
-    same jitted program on the replicated post-window state.
+    The scan is `repro.core.whfl.make_chunk_fn`'s over the `_round`
+    the per-round entry point runs (the stepwise driver's key chain:
+    ``key, sub = split(key)`` per round — threefry is integer-exact and
+    replicated identically on every shard), so chunked sharded sweeps
+    are bitwise equal to stepwise sharded sweeps AND retain the
+    engine's bitwise mesh-invariance.  `eval_fn(state)` (optional) is
+    folded into the same jitted program on the replicated post-window
+    state, outside the shard_map.
     """
-    _round, state_spec, X, Y = _build_round_parts(
-        loss_fn, opt, topo, cfg, spec, X, Y, mesh,
-        trace_counter=trace_counter, combine=combine)
+    _round, X, Y = _build_round(loss_fn, opt, topo, cfg, spec, X, Y, mesh,
+                                trace_counter=trace_counter,
+                                combine=combine)
 
-    def _chunk(state, key, P_win, P_is_win, X_loc, Y_loc):
-        def body(carry, Ps):
-            st, k = carry
-            ks = jax.random.split(k)
-            st = _round(st, ks[1], Ps[0], Ps[1], X_loc, Y_loc)
-            return (st, ks[0]), None
-
-        (state, key), _ = jax.lax.scan(body, (state, key),
-                                       (P_win, P_is_win))
+    def _window(state, key, P_win, P_is_win, X_loc, Y_loc):
+        window = make_chunk_fn(
+            lambda st, k, P_t, P_is_t: _round(st, k, P_t, P_is_t, X_loc,
+                                              Y_loc))
+        state, key, _ = window(state, key, P_win, P_is_win)
         return state, key
 
-    sharded = shard_map(
-        _chunk, mesh=mesh,
-        in_specs=(state_spec, P(), P(), P(),
-                  P("cluster", "user"), P("cluster", "user")),
-        out_specs=(state_spec, P()), check_vma=False)
-
     def chunk_fn(state, key, P_win, P_is_win):
+        spec = _state_spec(state)
+        sharded = shard_map(_window, mesh=mesh,
+                            in_specs=(spec, P(), P(), P(), _USERS, _USERS),
+                            out_specs=(spec, P()), check_vma=False)
         state, key = sharded(state, key,
                              jnp.asarray(P_win, jnp.float32),
                              jnp.asarray(P_is_win, jnp.float32), X, Y)

@@ -4,9 +4,9 @@ The convergence bound of arXiv:2207.09232 is written in quantities the
 trainer never surfaced: path-loss-weighted receive power at each IS,
 the effective post-matched-filter noise variance, the second moment of
 the aggregated update.  This module computes them *inside* the round
-function — `repro.core.whfl.make_round_fn` and
-`repro.exec.round.make_sharded_round_fn` call in with values they
-already materialize (flat per-user deltas, fold outputs, participation
+function — the one round body both engines run
+(`repro.core.whfl.make_round_body`) calls in with values it
+already materializes (flat per-user deltas, fold outputs, participation
 masks), so telemetry adds no extra hop and no host sync; the chunked
 drivers carry the block through the scan and fetch it with the
 round metrics in the same single `device_get`.

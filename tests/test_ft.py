@@ -391,6 +391,46 @@ def test_kill_at_round_and_resume_bitwise_cli(tmp_path):
             assert sb["exec"]["resumed_from"] == 5
 
 
+@pytest.mark.parametrize("scenario", ["fig2_iid", "fig2_iid_conventional"])
+def test_guard_poison_telemetry_single_vs_padded_sharded_bitwise(scenario):
+    """The guard, the poison and the telemetry block run the one round
+    body on both engines: fig2's (C=4, M=5) grid, a NaN poisoned into
+    user (2, 4) at round 1 and zero-filled by the guard, swept on the
+    single engine (``batch="map"``) and on a padded 2x2 mesh (4x6),
+    gives bitwise-equal metrics, final state (``guard_trips`` and the
+    telemetry block included) and telemetry trajectories; NaN compares
+    equal to NaN, as `repro.obs.diff` counts it."""
+    from conftest import run_forced_devices
+    out = run_forced_devices(f"""
+        import jax
+        import numpy as np
+        from repro.exec import ShardedSweepRunner
+        from repro.ft import FaultPlan
+        from repro.obs.diff import diff_trees
+        from repro.sim import SweepRunner, get_scenario
+
+        sc = get_scenario({scenario!r}).quick().replace(
+            C=4, M=5, total_IT=4, eval_every=1, telemetry=True)
+        kw = dict(seeds=[0], keep_state=True, guard="zero_fill",
+                  faults=FaultPlan.parse("poison=nan@1:2:4"))
+        ref = SweepRunner([sc], batch="map", **kw).run_scenario(sc)
+        sh = ShardedSweepRunner([sc], mesh="2x2", **kw).run_scenario(sc)
+        assert sh.exec_info["padded"] == "4x6", sh.exec_info
+        assert ref.exec_info["guard_trips"] >= 1, ref.exec_info
+        assert sh.exec_info["guard_trips"] == ref.exec_info["guard_trips"]
+        res = diff_trees(ref.to_record(), sh.to_record())
+        assert not res.errors and res.max_ulp == 0, (res.errors, res.ulps)
+        eq = jax.tree.map(
+            lambda a, b: bool(np.array_equal(np.asarray(a), np.asarray(b),
+                                             equal_nan=True)),
+            ref.final_state, sh.final_state)
+        assert jax.tree.all(eq), eq
+        assert ref.telemetry and "telemetry" in ref.final_state
+        print("ENGINES_OK")
+    """, n_dev=4)
+    assert "ENGINES_OK" in out
+
+
 @pytest.mark.slow
 def test_ft_cross_mesh_resume(tmp_path):
     """A checkpoint cut on a padded 2x4 mesh resumes on 1x1 bitwise
