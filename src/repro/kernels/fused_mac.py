@@ -120,25 +120,59 @@ def _k_stride(K: int) -> int:
 # counter-based PRNG: threefry2x32 + Box-Muller, pure jnp uint32 ops
 # ---------------------------------------------------------------------------
 
+_ROTATIONS = (13, 15, 26, 6, 17, 29, 16, 24)   # round n rotates by [n % 8]
+
+
 def _rotl(x, r: int):
     return (x << r) | (x >> (32 - r))
+
+
+def _threefry_head(k0, k1, c0, c1, d0=0, d1=0):
+    """The part of threefry2x32's round 1 that is affine in its inputs,
+    on the counter words (c0 + d0, c1 + d1) with d0, d1 scalars:
+    ``(x0, y)`` with x0 = c0 + (d0 + d1 + k0 + k1) + c1, the first word
+    after the round's add, and y = c1 + (d1 + k1), the second before its
+    rotation.  The scalar offsets fold into the key words.
+
+    Every add is mod 2^32, so shifting the counters or the keys by
+    scalars shifts x0 and y by scalars: the kernel's users of one tile,
+    whose counters differ by j * Kstride in c0, share one head and start
+    their `_threefry_tail` at ``x0 + j * Kstride``."""
+    return c0 + (d0 + d1 + k0 + k1) + c1, c1 + (d1 + k1)
+
+
+def _rekey_head(head, keys, new_keys, d0=0):
+    """The `_threefry_head` of the counters (c0 - d0, c1) under
+    `new_keys`, from `head`, that of (c0, c1) under `keys`: both words
+    shift by scalars."""
+    (x0, y), (k0, k1), (j0, j1) = head, keys, new_keys
+    return x0 + (j0 + j1 - k0 - k1 - d0), y + (j1 - k1)
+
+
+def _threefry_tail(k0, k1, x0, y):
+    """The rest of threefry2x32 from `_threefry_head`: round 1's
+    rotation and xor, rounds 2 to 20 and the five key injections.  Each
+    injection adds one key-schedule word to each half: injection s's
+    constant s is folded into its key word first, a scalar add wherever
+    the keys are scalars."""
+    ks = (k0, k1, k0 ^ k1 ^ np.uint32(0x1BD11BDA))
+
+    def inject(s, x0, x1):                     # injection s = 1..5
+        return x0 + ks[s % 3], x1 + (ks[(s + 1) % 3] + np.uint32(s))
+
+    x1 = _rotl(y, _ROTATIONS[0]) ^ x0
+    for n in range(1, 20):
+        if n % 4 == 0:
+            x0, x1 = inject(n // 4, x0, x1)
+        x0 = x0 + x1
+        x1 = _rotl(x1, _ROTATIONS[n % 8]) ^ x0
+    return inject(5, x0, x1)
 
 
 def _threefry2x32(k0, k1, x0, x1):
     """The 20-round threefry2x32 block cipher (matches jax.random's
     generator algorithm; arbitrary uint32 array shapes)."""
-    rotations = ((13, 15, 26, 6), (17, 29, 16, 24))
-    ks = (k0, k1, k0 ^ k1 ^ np.uint32(0x1BD11BDA))
-    x0 = x0 + ks[0]
-    x1 = x1 + ks[1]
-    for i in range(5):
-        for r in rotations[i % 2]:
-            x0 = x0 + x1
-            x1 = _rotl(x1, r)
-            x1 = x1 ^ x0
-        x0 = x0 + ks[(i + 1) % 3]
-        x1 = x1 + ks[(i + 2) % 3] + np.uint32(i + 1)
-    return x0, x1
+    return _threefry_tail(k0, k1, *_threefry_head(k0, k1, x0, x1))
 
 
 def _sincos_turn24(m):
@@ -176,10 +210,10 @@ def _box_muller(b0, b1):
     return r * cos_, r * sin_
 
 
-def _cx_normal(key0, key1, w0, w1, sigma: float):
-    """Per-element CN(0, 2*sigma^2) draw: (re, im) each N(0, sigma^2)."""
-    b0, b1 = _threefry2x32(key0, key1, w0, w1)
-    n0, n1 = _box_muller(b0, b1)
+def _cx_normal(key0, key1, x0, y, sigma: float):
+    """Per-element CN(0, 2*sigma^2) draw, (re, im) each N(0, sigma^2),
+    from the block's `_threefry_head` words (x0, y)."""
+    n0, n1 = _box_muller(*_threefry_tail(key0, key1, x0, y))
     return sigma * n0, sigma * n1
 
 
@@ -209,19 +243,31 @@ def _counter_words(seed, rx_base, u_base, n_base):
 
 
 def _tile_counters(words_ref, bk: int, bn: int):
-    """(rx, kk, nn): this grid step's rx counter word and the [bk, bn]
-    antenna / symbol counter words of its (k, n) tile."""
+    """(rx, sk, ln, k0, n0): this grid step's rx counter word and its
+    (k, n) tile's antenna and symbol counter words kk = sk + k0 and
+    nn = ln + n0, each as a [bk, bn] iota (sublane sk, lane ln) and a
+    scalar base, kept apart so that the bases fold into the key words
+    (`_threefry_head`)."""
     rx = words_ref[2] + pl.program_id(0).astype(jnp.uint32)
     k0 = (pl.program_id(2) * bk).astype(jnp.uint32)
-    n0 = (pl.program_id(1) * bn).astype(jnp.uint32)
-    kk = jax.lax.broadcasted_iota(jnp.uint32, (bk, bn), 0) + k0
-    nn = (jax.lax.broadcasted_iota(jnp.uint32, (bk, bn), 1) + n0
-          + words_ref[4])
-    return rx, kk, nn
+    n0 = (pl.program_id(1) * bn).astype(jnp.uint32) + words_ref[4]
+    sk = jax.lax.broadcasted_iota(jnp.uint32, (bk, bn), 0)
+    ln = jax.lax.broadcasted_iota(jnp.uint32, (bk, bn), 1)
+    return rx, sk, ln, k0, n0
 
 
-def _block_sums(words_ref, t_re_ref, t_im_ref, amp_ref, w_ref, rx, kk, nn,
-                *, Kstride: int, sigma_h: float, bu: int):
+def _channel_head(words_ref, rx, sk, ln, k0, n0, *, Kstride: int, bu: int):
+    """(keys, head, d): the channel stream's keys (hk0, hk1) and the
+    `_threefry_head` of this u-block's first user u0 on its (k, n)
+    tile, whose counters are (u0 * Kstride + kk, nn); d = u0 * Kstride."""
+    hk0, hk1 = _stream_keys(words_ref[0], words_ref[1], rx, _TAG_CHAN)
+    u0 = (pl.program_id(3) * bu).astype(jnp.uint32) + words_ref[3]
+    d = u0 * np.uint32(Kstride)
+    return (hk0, hk1), _threefry_head(hk0, hk1, sk, ln, k0 + d, n0), d
+
+
+def _block_sums(t_re_ref, t_im_ref, amp_ref, w_ref, keys, head, *,
+                Kstride: int, sigma_h: float, bu: int):
     """This u-block's accumulators, each [bk, bn]:
 
         r  = sum_u h[u] t[u]      mf = sum_u w[u] h[u]
@@ -233,16 +279,19 @@ def _block_sums(words_ref, t_re_ref, t_im_ref, amp_ref, w_ref, rx, kk, nn,
     each user's channel tile is drawn, folded and dropped, so no
     [bu, bk, bn] temporary ever exists.  amp/w are per-user scalars
     read from SMEM; each t row broadcasts over the antenna sublanes.
+
+    `keys` and `head` are `_channel_head`'s: user u0 + j's counter is
+    user u0's plus j * Kstride in the first word, so its draw starts
+    from the shared head's x0 plus that scalar (a zero for the static
+    j = 0, which Mosaic folds away).
     """
-    hk0, hk1 = _stream_keys(words_ref[0], words_ref[1], rx, _TAG_CHAN)
-    u0 = (pl.program_id(3) * bu).astype(jnp.uint32) + words_ref[3]
+    x0, y = head
 
     def user(j, t_re, t_im, acc):
         a = amp_ref[0, j]
         wa = w_ref[0, j] * a                 # matched filter uses w_u * h_u
-        uu = u0 + jax.lax.convert_element_type(j, jnp.uint32)
-        g_re, g_im = _cx_normal(hk0, hk1, uu * np.uint32(Kstride) + kk, nn,
-                                sigma_h)
+        dj = jax.lax.convert_element_type(j, jnp.uint32) * np.uint32(Kstride)
+        g_re, g_im = _cx_normal(*keys, x0 + dj, y, sigma_h)
         h_re, h_im = a * g_re, a * g_im
         r_re, r_im, mf_re, mf_im = acc
         return (r_re + (h_re * t_re - h_im * t_im),
@@ -256,7 +305,7 @@ def _block_sums(words_ref, t_re_ref, t_im_ref, amp_ref, w_ref, rx, kk, nn,
             acc = user(start + i, t_re[i:i + 1], t_im[i:i + 1], acc)
         return acc
 
-    zero = jnp.zeros(kk.shape, jnp.float32)
+    zero = jnp.zeros(x0.shape, jnp.float32)
     acc = (zero, zero, zero, zero)
     n_full = bu // _GROUP
     if n_full:
@@ -282,21 +331,25 @@ def _fused_kernel(words_ref, t_re_ref, t_im_ref, amp_ref, w_ref, y_ref,
     K grid axis.
     """
     ki, ui = pl.program_id(2), pl.program_id(3)
-    rx, kk, nn = _tile_counters(words_ref, bk, bn)
+    rx, sk, ln, k0, n0 = _tile_counters(words_ref, bk, bn)
+    keys, head, d = _channel_head(
+        words_ref, rx, sk, ln, k0, n0, Kstride=Kstride, bu=bu)
 
     @pl.when(ui == 0)
     def _init_block():
-        # receiver noise z ~ CN(0, sigma_z2) seeds the r accumulator
-        zk0, zk1 = _stream_keys(words_ref[0], words_ref[1], rx, _TAG_NOISE)
-        z_re, z_im = _cx_normal(zk0, zk1, kk, nn, sigma_z)
+        # receiver noise z ~ CN(0, sigma_z2) seeds the r accumulator;
+        # its counters (kk, nn) are user u0's less d in the first word
+        zk = _stream_keys(words_ref[0], words_ref[1], rx, _TAG_NOISE)
+        z_re, z_im = _cx_normal(*zk, *_rekey_head(head, keys, zk, d),
+                                sigma_z)
         r_re[...] = z_re
         r_im[...] = z_im
         mf_re[...] = jnp.zeros_like(mf_re)
         mf_im[...] = jnp.zeros_like(mf_im)
 
     pr_re, pr_im, pm_re, pm_im = _block_sums(
-        words_ref, t_re_ref, t_im_ref, amp_ref, w_ref, rx, kk, nn,
-        Kstride=Kstride, sigma_h=sigma_h, bu=bu)
+        t_re_ref, t_im_ref, amp_ref, w_ref, keys, head, Kstride=Kstride,
+        sigma_h=sigma_h, bu=bu)
     r_re[...] += pr_re
     r_im[...] += pr_im
     mf_re[...] += pm_re
@@ -308,8 +361,9 @@ def _fused_kernel(words_ref, t_re_ref, t_im_ref, amp_ref, w_ref, y_ref,
         def _init_out():
             y_ref[...] = jnp.zeros_like(y_ref)
 
-        # padded antenna rows carry generated garbage: mask them out
-        mask = (kk < np.uint32(K)).astype(jnp.float32)
+        # padded antenna rows (kk = sk + k0 >= K) carry generated
+        # garbage: mask them out
+        mask = (sk < np.uint32(K) - k0).astype(jnp.float32)
         a, b = mf_re[...], mf_im[...]
         p, q = r_re[...], r_im[...]
         y_ref[0:1, :] += jnp.sum(mask * (a * p + b * q), axis=0,
@@ -432,10 +486,12 @@ def _fused_partial_kernel(words_ref, t_re_ref, t_im_ref, amp_ref, w_ref,
     (`fused_partials_reduce`).  No noise: z is a separate term keyed on
     the same counter stream (`fused_noise`).
     """
-    rx, kk, nn = _tile_counters(words_ref, bk, bn)
+    keys, head, _ = _channel_head(
+        words_ref, *_tile_counters(words_ref, bk, bn), Kstride=Kstride,
+        bu=bu)
     pr_re, pr_im, pm_re, pm_im = _block_sums(
-        words_ref, t_re_ref, t_im_ref, amp_ref, w_ref, rx, kk, nn,
-        Kstride=Kstride, sigma_h=sigma_h, bu=bu)
+        t_re_ref, t_im_ref, amp_ref, w_ref, keys, head, Kstride=Kstride,
+        sigma_h=sigma_h, bu=bu)
     pr_re_ref[...] = pr_re
     pr_im_ref[...] = pr_im
     pm_re_ref[...] = pm_re
@@ -531,7 +587,7 @@ def fused_noise(seed, B: int, K: int, N: int, sigma_z2: float,
 
     def one_rx(b):
         zk0, zk1 = _stream_keys(seed[0], seed[1], b, _TAG_NOISE)
-        return _cx_normal(zk0, zk1, w0, w1, s_z)
+        return _cx_normal(zk0, zk1, *_threefry_head(zk0, zk1, w0, w1), s_z)
 
     rx0 = jnp.asarray(rx_base, jnp.uint32)
     return jax.lax.map(one_rx, jnp.arange(B, dtype=jnp.uint32) + rx0)
@@ -626,8 +682,10 @@ def fused_channels(seed, B: int, U: int, K: int, N: int, sigma_h2: float,
     def one_rx(b):
         hk0, hk1 = _stream_keys(seed[0], seed[1], b, _TAG_CHAN)
         zk0, zk1 = _stream_keys(seed[0], seed[1], b, _TAG_NOISE)
-        g = jax.lax.complex(*_cx_normal(hk0, hk1, w0_h, w1_h, s_h))
-        z = jax.lax.complex(*_cx_normal(zk0, zk1, w0_z, w1_z, s_z))
+        g = jax.lax.complex(*_cx_normal(
+            hk0, hk1, *_threefry_head(hk0, hk1, w0_h, w1_h), s_h))
+        z = jax.lax.complex(*_cx_normal(
+            zk0, zk1, *_threefry_head(zk0, zk1, w0_z, w1_z), s_z))
         return g, z
 
     rx0 = jnp.asarray(rx_base, jnp.uint32)

@@ -5,6 +5,9 @@ in-kernel counter PRNG derives *exactly* the channels `fused_channels`
 materializes, independent of blocking, and the fused fold agrees with
 the einsum oracle to float-accumulation error (<= 1e-4 relative).
 """
+import functools
+import hashlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,7 +16,10 @@ import pytest
 from repro.kernels import (assert_draw_invariance, canonical_block_u,
                            fused_channels, fused_mac, fused_mac_partials,
                            fused_mac_ref, fused_noise, fused_partials_reduce)
-from repro.kernels.fused_mac import _box_muller, _sincos_turn24
+from repro.kernels.fused_mac import (_TAG_CHAN, _TAG_NOISE, _box_muller,
+                                     _k_stride, _rekey_head, _sincos_turn24,
+                                     _stream_keys, _threefry2x32,
+                                     _threefry_head, _threefry_tail)
 
 SEED = jnp.asarray([0xC0FFEE, 42], jnp.uint32)
 
@@ -211,6 +217,44 @@ def test_partials_pinned_fold_bitwise_equals_full_call(U, K, n_tiles, N):
     np.testing.assert_array_equal(np.asarray(f_im), np.asarray(y_im))
 
 
+# sha256 of the float32 bytes of (y_re, y_im) and of the four partial
+# blocks, recorded before threefry's key-schedule and counter adds were
+# regrouped: uint32 addition is associative mod 2^32, so not one output
+# bit may move.  bu = 5 runs the static user tail, bu = 16 the
+# `fori_loop` over 8-user groups; the counter bases are all nonzero.
+_GOLDEN_BITS = {
+    (5, 20): ("6290432348543b3ccade2f7f4cf6b3d0bca61c61f7a0061b06e1f81285b5f5a9",
+              "3e68493682d4454a5ba972dd8707afa2a37881db61ac9f8898440b4a4018336f"),
+    (16, 32): ("7624ea7524d66c9ce136343f1771081a71e5803db531bce1ad986b0fa60417e6",
+               "f63edab2b3a66c543e126eb3e16f60599b279436f895afa3fd73d06e182a80db"),
+}
+
+
+def _sha256(arrays):
+    digest = hashlib.sha256()
+    for a in arrays:
+        digest.update(np.asarray(a, np.float32).tobytes())
+    return digest.hexdigest()
+
+
+@pytest.mark.parametrize("kernel", ["fused_mac", "fused_mac_partials"])
+@pytest.mark.parametrize("bu,U", sorted(_GOLDEN_BITS))
+def test_kernel_output_bits_pinned(kernel, bu, U):
+    """Every output bit of both kernels at a small shape (B = 2, K = 12
+    padded to 16, N = 300 padded to 384 in three symbol blocks) equals
+    its recorded digest."""
+    rng = np.random.default_rng(1000 + bu)
+    t_re, t_im, amp, w = _mk(rng, 2, U, 300)
+    kw = dict(K=12, sigma_h2=1.0, block_n=128, block_k=8, block_u=bu,
+              rx_base=3, u_base=7, n_base=1000, interpret=True)
+    if kernel == "fused_mac":
+        out = fused_mac(SEED, t_re, t_im, amp, w, sigma_z2=2.0, **kw)
+    else:
+        out = fused_mac_partials(SEED, t_re, t_im, amp, w, **kw)
+    want = _GOLDEN_BITS[bu, U][kernel == "fused_mac_partials"]
+    assert _sha256(out) == want
+
+
 def test_partials_require_aligned_u():
     rng = np.random.default_rng(0)
     t_re, t_im, amp, w = _mk(rng, 1, 12, 64)
@@ -296,6 +340,106 @@ def test_box_muller_matches_float64():
     theta = 2.0 * np.pi * (b1 >> 8).astype(np.float64) / 2 ** 24
     assert np.abs(np.asarray(n0, np.float64) - r * np.cos(theta)).max() < 1e-6
     assert np.abs(np.asarray(n1, np.float64) - r * np.sin(theta)).max() < 1e-6
+
+
+def _jax_threefry(k0, k1, x0, x1):
+    """JAX's own threefry2x32 primitive, elementwise over broadcast
+    uint32 arrays."""
+    from jax.extend.random import threefry2x32_p
+    args = jnp.broadcast_arrays(*(jnp.asarray(v, jnp.uint32)
+                                  for v in (k0, k1, x0, x1)))
+    return threefry2x32_p.bind(*args)
+
+
+def test_threefry_matches_jax_primitive():
+    """`_threefry2x32` is JAX's threefry2x32 bit for bit: random
+    per-element keys and counters, with every counter within 2^8 of
+    2^32 in either word (the adds wrap); and scalar keys over a vector
+    of counters, as every caller draws."""
+    rng = np.random.default_rng(2 ** 32 + 20)
+    n = 4096
+    k0, k1, x0, x1 = rng.integers(0, 2 ** 32, (4, n + 768),
+                                  dtype=np.uint64).astype(np.uint32)
+    top = (np.arange(256, dtype=np.uint64) + 2 ** 32 - 256).astype(np.uint32)
+    x0[n:n + 256] = top
+    x1[n + 256:n + 512] = top
+    x0[n + 512:], x1[n + 512:] = top, top[::-1]
+    ours = jax.jit(_threefry2x32)
+    for keys in ((k0, k1), (k0[0], k1[0])):
+        got = ours(*(jnp.asarray(v) for v in (*keys, x0, x1)))
+        want = _jax_threefry(*keys, x0, x1)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_threefry_head_shared_by_a_tiles_users():
+    """The kernel's split of threefry2x32: one `_threefry_head` for a
+    [bk, bn] tile at its u-block's first user u0, counter words
+    (u0 * Kstride + sk + k0, ln + n0); user u0 + j runs
+    `_threefry_tail` from x0 + j * Kstride, and the noise stream from
+    the head rekeyed to its keys and counters (sk + k0, ln + n0).  Both
+    equal JAX's threefry2x32 on the full counters for every user of the
+    tile.  The bases put both counter words across 2^32 inside the
+    tile."""
+    K, bk, bn, bu = 100, 8, 512, 5
+    Kstride = np.uint32(_k_stride(K))
+    sk = jax.lax.broadcasted_iota(jnp.uint32, (bk, bn), 0)
+    ln = jax.lax.broadcasted_iota(jnp.uint32, (bk, bn), 1)
+    k0 = jnp.uint32(96)
+    n0 = jnp.uint32(2 ** 32 - 300)
+    u0 = jnp.uint32(2 ** 32 // int(Kstride) - 2)
+    keys = _stream_keys(SEED[0], SEED[1], 3, _TAG_CHAN)
+    zk = _stream_keys(SEED[0], SEED[1], 3, _TAG_NOISE)
+    d = u0 * Kstride
+    head = _threefry_head(*keys, sk, ln, k0 + d, n0)
+    for j in range(bu):
+        got = _threefry_tail(*keys, head[0] + jnp.uint32(j) * Kstride,
+                             head[1])
+        want = _jax_threefry(*keys, (u0 + jnp.uint32(j)) * Kstride + sk + k0,
+                             ln + n0)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    got = _threefry_tail(*zk, *_rekey_head(head, keys, zk, d))
+    want = _jax_threefry(*zk, sk + k0, ln + n0)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def _vector_word_adds(jaxpr):
+    """Number of `add` equations on uint32 arrays (not scalars) in
+    `jaxpr` and every jaxpr nested in it."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        aval = eqn.outvars[0].aval if eqn.outvars else None
+        if (eqn.primitive.name == "add" and aval.dtype == jnp.uint32
+                and aval.ndim):
+            n += 1
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    n += _vector_word_adds(inner)
+    return n
+
+
+def _partials_word_adds(bu: int) -> int:
+    t = jnp.zeros((bu, 128), jnp.float32)
+    a = jnp.zeros((1, bu), jnp.float32)
+    fn = functools.partial(fused_mac_partials, K=8, sigma_h2=1.0,
+                           block_n=128, block_k=8, block_u=bu,
+                           interpret=False)
+    return _vector_word_adds(jax.make_jaxpr(fn)(SEED, t, t, a, a).jaxpr)
+
+
+def test_user_draw_has_no_per_element_scalar_adds():
+    """One user's channel draw in `_block_sums` adds its threefry words
+    elementwise 30 times: its start x0 + j * Kstride, rounds 2 to 20,
+    and the ten injection adds.  The key schedule's constants and the
+    user's counter base are scalar adds, and the tile's head (x0 and y:
+    three adds) is shared by the u-block's users: per-element adds of
+    scalars, 38 a user before, may not come back."""
+    assert _partials_word_adds(3) - _partials_word_adds(2) == 1 + 19 + 10
+    assert _partials_word_adds(1) == 3 + 1 + 19 + 10
 
 
 def _primitive_names(jaxpr):
